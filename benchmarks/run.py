@@ -54,6 +54,8 @@ import os
 import sys
 import time
 
+from repro.compile_cache import enable_compilation_cache
+
 from benchmarks import (
     ablations,
     adaptivity,
@@ -132,26 +134,6 @@ def check_baseline(name: str, rows, baseline_dir: str, tolerance: float):
     return ok, records
 
 
-def _enable_compilation_cache() -> None:
-    """Persistent JAX compilation cache: cuts re-trace time across runs.
-
-    CI points JAX_COMPILATION_CACHE_DIR at an actions/cache'd directory so
-    repeated benchmark jobs skip recompiling unchanged programs.  Guarded:
-    older jax builds without the config knobs just run uncached.
-    """
-    import jax
-
-    cache_dir = os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "jax_bench"),
-    )
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception as e:  # pragma: no cover
-        print(f"# compilation cache unavailable: {e}", file=sys.stderr)
-
 BENCHMARKS = {
     "fig1_4_temporal_pattern": temporal_pattern.run,
     "fig5_6_selection_patterns": selection_patterns.run,
@@ -173,7 +155,7 @@ BENCHMARKS = {
 
 
 def main() -> int:
-    _enable_compilation_cache()
+    enable_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, help="substring filter")
     ap.add_argument(

@@ -107,14 +107,20 @@ def topm_extract(rho: Array, top_m: int) -> tuple[Array, Array]:
     dtype = rho.dtype
     inf = jnp.asarray(jnp.inf, dtype)
     work0 = jnp.where(rho > _RHO_ZERO_TOL, rho, inf)
-    iota = jnp.arange(K, dtype=jnp.int32)
+    iota = jax.lax.broadcasted_iota(jnp.int32, (K,), 0)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (top_m,), 0)
 
     def extract(j, carry):
         work, vals, idx = carry
         v = jnp.min(work)
-        i = jnp.argmin(work).astype(jnp.int32)  # first occurrence on ties
+        # First occurrence on ties, as an index-min (no argmin gather).
+        i = jnp.min(jnp.where(work == v, iota, K))
+        i = jnp.where(i < K, i, 0)   # all-inf work: slot exhausted, index 0
         work = jnp.where(iota == i, inf, work)
-        return work, vals.at[j].set(v), idx.at[j].set(i)
+        # Masked selects, not ``.at[j].set``: a dynamic update lowers to a
+        # scatter, which the fused kernel's TPU compiler cannot lower.
+        hit = slot == j
+        return work, jnp.where(hit, v, vals), jnp.where(hit, i, idx)
 
     _, vals, idx = jax.lax.fori_loop(
         0,
@@ -310,12 +316,15 @@ def _ocean_p_topm(
             # Reconstruct the K-length sorted view: extracted values land
             # at their exact sorted offsets [n0, n0 + m_cands); everything
             # else is a +inf sentinel no masked candidate reduction ever
-            # reads.  The buffer is (K + m_cands) long so the traced start
-            # offset n0 never clamps (dynamic_update_slice clips
-            # out-of-bounds starts).
-            buf = jnp.full((K + m_cands,), jnp.inf, dtype)
-            buf = jax.lax.dynamic_update_slice(buf, vals, (n0,))
-            rho_rank = buf[:K]
+            # reads.  One-hot (m_cands, K) selects stand in for a dynamic
+            # update / slice / scatter so the round also lowers inside
+            # the fused TPU kernel; each picks one exact value (or none).
+            ranks = jax.lax.broadcasted_iota(jnp.int32, (m_cands, K), 1)
+            slots = jax.lax.broadcasted_iota(jnp.int32, (m_cands, K), 0)
+            at_rank = ranks == n0 + slots                 # (m_cands, K)
+            rho_rank = jnp.min(
+                jnp.where(at_rank, vals[:, None], jnp.inf), axis=0
+            )
         rho_hi = jnp.max(rho)  # order-insensitive == rho_sorted[K-1]
         with trace_span(f"ocean/p4_solve/{backend.name}"):
             sol = backend.prefixes(
@@ -331,16 +340,16 @@ def _ocean_p_topm(
             )
         m_star = sol.m_star
         w_star = sol.w_star
-        # Winner's allocation lives at sorted slots [n0, n0 + m*); slice
-        # the candidate window and scatter through the extraction indices
-        # (exhausted slots carry idx 0 but sel_j False / +0.0 adds).
-        bpad = jnp.concatenate([sol.b_pos_sorted, jnp.zeros((m_cands,), dtype)])
-        b_cand = jax.lax.dynamic_slice(bpad, (n0,), (m_cands,))
-        sel_j = jnp.arange(m_cands) < m_star
-        b_pos = (
-            jnp.zeros((K,), dtype).at[idx].add(jnp.where(sel_j, b_cand, 0.0))
+        # Winner's allocation lives at sorted slots [n0, n0 + m*): read
+        # the candidate window, then scatter through the extraction
+        # indices (exhausted slots carry idx 0 but sel_j False / +0.0).
+        b_cand = jnp.sum(
+            jnp.where(at_rank, sol.b_pos_sorted[None, :], 0.0), axis=1
         )
-        sel_pos = jnp.zeros((K,), bool).at[idx].max(sel_j)
+        sel_j = slots[:, :1] < m_star                     # (m_cands, 1)
+        at_client = (idx[:, None] == ranks) & sel_j       # (m_cands, K)
+        b_pos = jnp.sum(jnp.where(at_client, b_cand[:, None], 0.0), axis=0)
+        sel_pos = jnp.any(at_client, axis=0)
 
     leftover = jnp.where(m_star == 0, delta, 0.0)
     b0_each = radio.b_min + leftover / jnp.maximum(n0.astype(dtype), 1.0)

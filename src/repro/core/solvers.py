@@ -98,6 +98,10 @@ NEWTON_OUTER_ITERS_X64 = 12
 NEWTON_INNER_ITERS_X64 = 14
 NEWTON_GRID_LEVELS_X64 = 13
 
+# Candidate prefixes the newton backend solves at once: one sublane tile
+# of the (K+1, K) lattice per step of its candidate sweep.
+CANDIDATE_BLOCK = 8
+
 # Budgets autotuned per (dtype, K-bucket).  Larger prefixes span more
 # orders of magnitude in the waterfilling level (lam_hi scales with
 # max rho over a wider pool) and the shared seeding grid covers each
@@ -430,7 +434,10 @@ def waterfill_newton(
         1e-30,
     )
     lam_lo_g = jnp.clip(lam_lo_g, 1e-30, lam_hi)
-    frac = jnp.linspace(0.0, 1.0, G).astype(rho.dtype)
+    # == jnp.linspace(0, 1, G), built from an integer iota.
+    frac = jax.lax.broadcasted_iota(jnp.int32, (G,), 0).astype(rho.dtype) / jnp.asarray(
+        G - 1, rho.dtype
+    )
     lam_grid = jnp.exp(
         jnp.log(lam_lo_g) * (1.0 - frac) + jnp.log(jnp.maximum(lam_hi, 1e-30)) * frac
     )
@@ -467,19 +474,28 @@ def _prefix_newton(
     m_cands: Optional[int] = None,
     rho_hi: Optional[Array] = None,
 ) -> PrefixSolution:
-    """All K+1 prefixes at once: shared-grid seeding + vectorized Newton.
+    """All K+1 prefixes: shared-grid seeding + blocked vectorized Newton.
 
     ``outer_iters``/``inner_iters`` are the *bisect* budgets and are
     ignored — Newton's own budgets (`NEWTON_*`) are an order of magnitude
     smaller because each step is superlinear.
 
     ``m_cands`` clips the candidate lattice to (m_cands+1, K) for the
-    sort-free top-m path: the masked cumulative sums only read slots the
+    sort-free top-m path: the masked prefix sums only read slots the
     extraction filled exactly, and ``rho_hi`` (the order-insensitive
     global ``max(rho)``) reproduces the full sweep's shared-grid anchor
     ``lam_hi_glob`` bit-for-bit — weakly monotone rounding makes
     ``max_m(rho_last_m * c + d) == max(rho) * c + d`` — so every
     surviving candidate matches the full lattice bitwise.
+
+    The candidates are swept ``CANDIDATE_BLOCK`` rows at a time, carrying
+    the running argmax (strict ``>``: ties keep the smaller m, as
+    ``argmax`` does).  Each row's math only reads its own row, so the
+    blocking changes no value; it bounds the live lattice to
+    (CANDIDATE_BLOCK, K), which is what lets the fused trajectory kernel
+    hold a large-K round on-chip.  Every data-dependent index is a
+    one-hot select that picks exactly one value — no gather, scatter or
+    ``cumsum``, which the TPU kernel compiler cannot lower.
     """
     del outer_iters, inner_iters
     dtype = rho_sorted.dtype
@@ -489,31 +505,20 @@ def _prefix_newton(
     K = rho_sorted.shape[0]
     beta = radio.beta
     b_min = radio.b_min
+    M = (K if m_cands is None else m_cands) + 1
 
-    ranks = jnp.arange(K)
-    ms = jnp.arange((K if m_cands is None else m_cands) + 1)
-    mf = ms.astype(dtype)
+    ranks = jax.lax.broadcasted_iota(jnp.int32, (K,), 0)
     pos = ranks >= n0                                        # positive-rho region
-    mask = pos[None, :] & (ranks[None, :] < n0 + ms[:, None])  # (K+1, K)
-    feasible = ms <= (K - n0)
-    b_max = jnp.maximum(delta - (jnp.maximum(ms, 1) - 1).astype(dtype) * b_min, b_min)
-
     fp_min = -f_shannon_prime(jnp.asarray(b_min, dtype), beta)
-    # Ascending sort => the prefix max rho is its last member.
-    last = jnp.clip(n0 + ms - 1, 0, K - 1)
-    rho_last = jnp.where(ms >= 1, jnp.take(rho_sorted, last), 0.0)
-    lam_hi = rho_last * fp_min * (1.0 + 1e-6) + 1e-30        # valid upper bracket
+    c_hi = fp_min * (1.0 + 1e-6)
 
-    # ---- shared-grid seeding: b(lam) once per level for all K clients,
-    # every prefix's residual via one masked cumulative sum  (O(G K)).
+    # ---- shared-grid seeding: b(lam) once per level for all K clients;
+    # each candidate block contracts it with its prefix masks below.
     G = n_grid
-    if rho_hi is None:
-        lam_hi_glob = jnp.max(lam_hi)
-    else:
-        # Same scalar op chain as the elementwise lam_hi above: rho >= 0 and
-        # each op is weakly monotone, so this equals max(lam_hi) of the full
-        # sweep bit-for-bit (whose max rho_last is the global max rho).
-        lam_hi_glob = rho_hi * fp_min * (1.0 + 1e-6) + 1e-30
+    # Ascending sort => max(rho_sorted) is the last rank, the largest
+    # prefix max; the top-m path passes the global max(rho) instead.
+    rho_hi = jnp.max(rho_sorted) if rho_hi is None else rho_hi
+    lam_hi_glob = rho_hi * c_hi + 1e-30
     rho_pos = jnp.where(pos & (rho_sorted > 0), rho_sorted, jnp.inf)
     rho_min_pos = jnp.min(rho_pos)
     b_cap_glob = jnp.maximum(delta, b_min)
@@ -523,57 +528,102 @@ def _prefix_newton(
         1e-30,
     )
     lam_lo_glob = jnp.clip(lam_lo_glob, 1e-30, lam_hi_glob)
-    frac = jnp.linspace(0.0, 1.0, G).astype(dtype)
+    # == jnp.linspace(0, 1, G), built from an integer iota.
+    frac = jax.lax.broadcasted_iota(jnp.int32, (G, 1), 0).astype(dtype) / jnp.asarray(
+        G - 1, dtype
+    )
     lam_grid = jnp.exp(
         jnp.log(lam_lo_glob) * (1.0 - frac) + jnp.log(jnp.maximum(lam_hi_glob, 1e-30)) * frac
-    )                                                        # (G,) ascending
+    )                                                        # (G, 1) ascending
     with trace_span("p4/newton/grid_seed"):
         bg = b_of_lam_newton(
-            lam_grid[:, None], rho_sorted[None, :], beta, b_min, b_cap_glob
+            lam_grid, rho_sorted[None, :], beta, b_min, b_cap_glob
         )                                                    # (G, K) shared
-    csum = jnp.cumsum(jnp.where(pos[None, :], bg, 0.0), axis=1)
-    csum0 = jnp.concatenate([jnp.zeros((G, 1), dtype), csum], axis=1)  # (G, K+1)
-    prefix_sums = jnp.take(csum0, jnp.clip(n0 + ms, 0, K), axis=1) - jnp.take(
-        csum0, jnp.clip(n0, 0, K)[None], axis=1
-    )                                                        # (G, K+1)
-    r_grid = prefix_sums - delta
-    # The grid uses the *global* cap (>= each candidate's), so r_grid is an
-    # over-estimate: "r <= 0" certifies a valid upper bracket, "r > 0" only
-    # seeds — the polish loop re-brackets from exact evaluations (lo0 = 0).
-    nonpos = r_grid <= 0
-    hi_seed = jnp.min(jnp.where(nonpos, lam_grid[:, None], jnp.inf), axis=0)
-    hi0 = jnp.minimum(jnp.where(jnp.isfinite(hi_seed), hi_seed, lam_hi), lam_hi)
-    lo_seed = jnp.max(jnp.where(~nonpos, lam_grid[:, None], 0.0), axis=0)
-    lam0 = jnp.clip(
-        jnp.sqrt(jnp.maximum(lo_seed, 1e-30) * jnp.maximum(hi0, 1e-30)),
-        0.0,
-        hi0,
-    )
+    bg = jnp.where(pos[None, :], bg, 0.0)
 
-    # ---- vectorized safeguarded Newton polish over the (K+1, K) lattice.
+    CB = CANDIDATE_BLOCK
+    rows = jax.lax.broadcasted_iota(jnp.int32, (CB, K), 0)
+    ranks2 = jax.lax.broadcasted_iota(jnp.int32, (CB, K), 1)
     rho_b = rho_sorted[None, :]
-    with trace_span("p4/newton/polish"):
-        b = _outer_newton_polish(
-            lam0, jnp.zeros_like(lam0), hi0, rho_b, mask, delta, beta, b_min,
-            b_max, n_outer, n_inner,
-        )
-    b = jnp.where(mask, b, 0.0)
-    b = _budget_repair(b, mask, delta, b_min, b_max[:, None])
-    cost = jnp.sum(
-        jnp.where(mask, rho_b * f_shannon(jnp.maximum(b, b_min), beta), 0.0), axis=1
-    )
-    has_any = ms > 0
-    b = jnp.where(has_any[:, None], b, 0.0)
-    cost = jnp.where(has_any, cost, 0.0)
 
-    w = v_eta * (n0.astype(dtype) + mf) - radio.energy_scale * cost
-    w = jnp.where(feasible, w, -jnp.inf)
-    best = jnp.argmax(w)
+    def candidate_block(jb, carry):
+        best_w, best_m, best_b = carry
+        ms2 = rows + jb * CB
+        ms = ms2[:, 0]
+        mask = (ranks2 >= n0) & (ranks2 < n0 + ms2)          # (CB, K)
+        feasible = (ms <= (K - n0)) & (ms < M)
+        b_max = jnp.maximum(
+            delta - (jnp.maximum(ms, 1) - 1).astype(dtype) * b_min, b_min
+        )
+        # Ascending sort => the prefix max rho is its last member.
+        last = jnp.clip(n0 + ms2 - 1, 0, K - 1)
+        rho_last = jnp.where(
+            ms >= 1,
+            jnp.sum(jnp.where(ranks2 == last, rho_b, 0.0), axis=1),
+            0.0,
+        )
+        lam_hi = rho_last * c_hi + 1e-30                     # valid upper bracket
+        # r_grid[g, m] = level g's b summed over candidate m's prefix.
+        r_grid = jax.lax.dot_general(
+            bg,
+            mask.astype(dtype),
+            (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+        ) - delta                                            # (G, CB)
+        # The grid uses the *global* cap (>= each candidate's), so r_grid
+        # over-estimates: "r <= 0" certifies a valid upper bracket, "r > 0"
+        # only seeds — the polish re-brackets from exact evaluations.
+        nonpos = r_grid <= 0
+        hi_seed = jnp.min(jnp.where(nonpos, lam_grid, jnp.inf), axis=0)
+        hi0 = jnp.minimum(jnp.where(jnp.isfinite(hi_seed), hi_seed, lam_hi), lam_hi)
+        lo_seed = jnp.max(jnp.where(~nonpos, lam_grid, 0.0), axis=0)
+        lam0 = jnp.clip(
+            jnp.sqrt(jnp.maximum(lo_seed, 1e-30) * jnp.maximum(hi0, 1e-30)),
+            0.0,
+            hi0,
+        )
+        with trace_span("p4/newton/polish"):
+            b = _outer_newton_polish(
+                lam0, jnp.zeros_like(lam0), hi0, rho_b, mask, delta, beta,
+                b_min, b_max, n_outer, n_inner,
+            )
+        b = jnp.where(mask, b, 0.0)
+        b = _budget_repair(b, mask, delta, b_min, b_max[:, None])
+        cost = jnp.sum(
+            jnp.where(mask, rho_b * f_shannon(jnp.maximum(b, b_min), beta), 0.0),
+            axis=1,
+        )
+        has_any = ms > 0
+        b = jnp.where(has_any[:, None], b, 0.0)
+        cost = jnp.where(has_any, cost, 0.0)
+
+        w = v_eta * (n0.astype(dtype) + ms.astype(dtype)) - radio.energy_scale * cost
+        w = jnp.where(feasible, w, -jnp.inf)
+        j = jnp.argmax(w).astype(jnp.int32)
+        win = rows == j                                      # one-hot row
+        w_j = jnp.max(jnp.where(ms == jb * CB + j, w, -jnp.inf))
+        better = w_j > best_w
+        return (
+            jnp.where(better, w_j, best_w),
+            jnp.where(better, jb * CB + j, best_m),
+            jnp.where(better, jnp.sum(jnp.where(win, b, 0.0), axis=0), best_b),
+        )
+
+    w_star, m_star, b_best = jax.lax.fori_loop(
+        0,
+        -(-M // CB),
+        candidate_block,
+        (
+            jnp.asarray(-jnp.inf, dtype),
+            jnp.zeros((), jnp.int32),
+            jnp.zeros((K,), dtype),
+        ),
+    )
     return PrefixSolution(
-        m_star=ms[best],
-        w_star=w[best],
-        b_pos_sorted=b[best],
-        sel_pos_sorted=mask[best],
+        m_star=m_star,
+        w_star=w_star,
+        b_pos_sorted=b_best,
+        sel_pos_sorted=pos & (ranks < n0 + m_star),
     )
 
 

@@ -17,17 +17,13 @@ kernel instead
 
 Scalars (n0, delta, V*eta, beta, b_min, energy_scale) arrive as one SMEM
 row so a traced per-round radio pytree (``repro.env.radio``) lowers
-straight into the kernel.  On non-TPU backends the kernel runs in
-interpret mode (same trace, compiled by XLA) — the CPU fallback used by
-tests and CI.  Parity is pinned against ``repro.kernels.ref``'s
-pure-jnp oracle in tests/test_solvers.py.
-
-CAVEAT: tests and CI are CPU-only, so only the interpret path is
-continuously validated; the compiled Mosaic path (auto-selected on TPU
-hosts) shares the trace but its SMEM/VMEM lowering has not run on real
-hardware yet — pass ``interpret=True`` explicitly to force the
-validated path, and see the ROADMAP PR-4 follow-up before relying on
-``solver="pallas"`` in a TPU production job.
+straight into the kernel, and the winner's (W*, m*) leave through an SMEM
+row.  On the CPU backend the kernel runs in interpret mode (same trace,
+compiled by XLA), which is how tests and CI run it; on a TPU it is
+compiled by Mosaic, with no interpret fallback.  Parity is pinned
+against ``repro.kernels.ref``'s pure-jnp oracle in tests/test_solvers.py;
+tests/test_tpu_compile.py compiles both kernels for a described v5e chip,
+and ``chip_smoke.py`` runs them on one.
 """
 from __future__ import annotations
 
@@ -42,7 +38,13 @@ NEG_INF = -1e30
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    # Only the CPU interprets; on a TPU a kernel compiles or the call fails.
+    return jax.default_backend() == "cpu"
+
+
+def _iota_f32(shape, dim):
+    # Mosaic's iota is integer-only; the f32 convert is exact for < 2^24.
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim).astype(jnp.float32)
 
 
 def _fused_kernel(
@@ -67,7 +69,7 @@ def _fused_kernel(
     scale = scal_ref[0, 5]
 
     rho = rho_ref[...]                                           # (1, K) resident
-    ranks = jax.lax.broadcasted_iota(jnp.float32, (1, K), 1)
+    ranks = _iota_f32((1, K), 1)
     pos = ranks >= n0
     kf = jnp.float32(K)
     fp_min = -f_shannon_prime(b_min, beta)                       # > 0 scalar
@@ -161,7 +163,7 @@ def ocean_p_prefixes_fused(
     """Backend-contract wrapper: solve all K+1 prefixes, return the winner.
 
     Returns a ``repro.core.solvers.PrefixSolution``.  ``interpret=None``
-    auto-selects interpret mode off-TPU (the CPU fallback).  ``n_cands``
+    interprets on the CPU backend and compiles everywhere else.  ``n_cands``
     (the sort-free top-m path) clips the sequential candidate sweep to
     m in [0, n_cands].
     """
@@ -200,7 +202,7 @@ def ocean_p_prefixes_fused(
         ]
         out_specs = (
             pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         )
     call_kwargs = {}
     if in_specs is not None:
@@ -276,9 +278,11 @@ def _topm_kernel(
 
     # ---- phase 1: tiled top-m extraction --------------------------------
     work0 = rho_ref[...].reshape(nb, block_k)
-    col = jax.lax.broadcasted_iota(jnp.float32, (nb, block_k), 1)
-    row = jax.lax.broadcasted_iota(jnp.float32, (nb, block_k), 0)
+    col = _iota_f32((nb, block_k), 1)
+    row = _iota_f32((nb, block_k), 0)
     gidx2d = row * jnp.float32(block_k) + col     # global client index
+
+    slot_iota = jax.lax.broadcasted_iota(jnp.int32, (1, top_m), 1)
 
     def extract(j, carry):
         work, vals, idxs = carry
@@ -287,10 +291,13 @@ def _topm_kernel(
         # first occurrence of the min — an index-min, not a gather
         gidx = jnp.min(jnp.where(work == gmin, gidx2d, jnp.float32(K_pad)))
         work = jnp.where(gidx2d == gidx, inf, work)
+        # Masked lane select, not ``.at[0, j].set``: a dynamic lane
+        # update lowers to a scatter, which Mosaic has no rule for.
+        slot = slot_iota == j
         return (
             work,
-            vals.at[0, j].set(gmin),
-            idxs.at[0, j].set(gidx),
+            jnp.where(slot, gmin, vals),
+            jnp.where(slot, gidx, idxs),
         )
 
     _, vals, idxs = jax.lax.fori_loop(
@@ -305,7 +312,7 @@ def _topm_kernel(
     )
 
     # ---- phase 2: compact candidate sweep over the extracted prefix -----
-    jcol = jax.lax.broadcasted_iota(jnp.float32, (1, top_m), 1)
+    jcol = _iota_f32((1, top_m), 1)
 
     def candidate(m, carry):
         best_w, best_m, best_b = carry
@@ -384,14 +391,12 @@ def _topm_kernel(
 
     def scatter(ib, _):
         base = (ib * block_k).astype(jnp.float32)
-        tile_iota = (
-            jax.lax.broadcasted_iota(jnp.float32, (1, block_k), 1) + base
-        )
+        tile_iota = _iota_f32((1, block_k), 1) + base
         onehot = idx_col == tile_iota              # (top_m, block_k)
         tile = jnp.sum(
             jnp.where(onehot, b_col, 0.0), axis=0, keepdims=True
         )                                          # (1, block_k)
-        pl.store(b_ref, (slice(0, 1), pl.ds(ib * block_k, block_k)), tile)
+        b_ref[pl.ds(0, 1), pl.ds(ib * block_k, block_k)] = tile
         return 0
 
     jax.lax.fori_loop(0, nb, scatter, 0)
@@ -475,7 +480,7 @@ def ocean_p_topm_fused(
             ],
             out_specs=(
                 pl.BlockSpec(memory_space=pltpu.VMEM),
-                pl.BlockSpec(memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
             ),
         )
     b2d, wm = pl.pallas_call(

@@ -37,13 +37,13 @@ Exposed as the ``fused`` trajectory backend of
 bit-stable default.  The pure-jnp parity oracle is
 ``repro.kernels.ref.ocean_traj_ref``.
 
-CAVEAT: tests and CI are CPU-only, so only the interpret path is
-continuously validated (the ``ocean_p`` kernel's caveat applies even
-more strongly here: the round body traces ``argsort`` and a vmapped
-candidate lattice, which the Mosaic TPU lowering has never compiled on
-real hardware).  Pass ``interpret=True`` to force the validated path;
-see the ROADMAP PR-5 follow-ups before relying on ``traj="fused"`` in a
-TPU production job.
+On the CPU backend the kernel runs in interpret mode (how tests and CI
+run it); on a TPU it is compiled by Mosaic, with no interpret fallback.
+Mosaic lowers the round body for ``solver="newton", ranking="topm"``
+only; every other configuration is refused by
+:func:`check_fused_lowerable` before anything compiles (use
+``traj="scan"`` there).  ``chip_smoke.py`` runs this kernel on a v5e
+chip against ``scan``.
 """
 from __future__ import annotations
 
@@ -86,11 +86,69 @@ DEFAULT_CHUNK = 32
 # tile (and the 9 output tiles mirroring it) still fits on-chip.
 CHUNK_ELEM_BUDGET = 1 << 16
 
+# A (chunk, K) tile's row count must be a multiple of the TPU's 8
+# sublanes unless one tile covers all T rounds.
+ROW_TILE = 8
+
 _N_RADIO_LEAVES = len(TracedRadio._fields)
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    # Only the CPU interprets; on a TPU a kernel compiles or the call fails.
+    return jax.default_backend() == "cpu"
+
+
+def check_fused_lowerable(
+    cfg: OceanConfig, has_failure: bool = False, stream_bf16: bool = False
+) -> None:
+    """Refuse, before compiling, a fused round body Mosaic cannot lower.
+
+    The compiled kernel supports ``solver="newton", ranking="topm"`` with
+    any radio process, ``plain``/``reallocate`` failure handling,
+    checkpoint segments and per-client telemetry.  Everything else raises
+    here, on a TPU, rather than fail deep inside the compiler or fall back
+    to interpretation.
+    """
+    refused = []
+    if cfg.ranking != "topm":
+        refused.append(f"ranking={cfg.ranking!r} (argsort in the round body)")
+    if cfg.solver != "newton":
+        why = {
+            "pallas": "a pallas_call nested in the kernel",
+            "pallas_tiled": "a pallas_call nested in the kernel",
+        }.get(cfg.solver, "a round body Mosaic does not lower")
+        refused.append(f"solver={cfg.solver!r} ({why})")
+    if cfg.metrics is not None:
+        # Per-client (K,) collectors lower with last/mean/full_trace; a
+        # per-round scalar is a scalar store to VMEM, a histogram a
+        # scatter-add and full_trace_ds a dynamic slice.
+        bad = [
+            f"{name}:{red}"
+            for name, red in cfg.metrics.collect
+            if red not in ("last", "mean", "full_trace")
+            or get_collector(name).shape(1) == ()
+        ]
+        if bad:
+            refused.append(f"metrics {', '.join(bad)} (only per-client "
+                           "collectors with last/mean/full_trace lower)")
+    if cfg.guard is not None:
+        refused.append("guard (its bisect fallback solve)")
+    if has_failure and cfg.failure_mode == "overprovision":
+        refused.append("failure_mode='overprovision' (argsort in the round body)")
+    if stream_bf16:
+        refused.append("stream_bf16 (bf16 rows need 16-row aligned stores)")
+    if refused:
+        raise ValueError(
+            "traj='fused' cannot compile for a TPU with "
+            + "; ".join(refused)
+            + ". The compiled kernel supports solver='newton', "
+            "ranking='topm'; run other configurations with traj='scan'."
+        )
+
+
+def _put(ref, i, value):
+    """Store a one-value round result into row ``i`` of a (chunk, 1) column."""
+    ref[pl.ds(i, 1), :] = jnp.reshape(value, (1, 1)).astype(ref.dtype)
 
 
 def _traj_kernel(
@@ -103,23 +161,24 @@ def _traj_kernel(
     has_init: bool = False,
 ):
     # stream_bf16: the per-round (chunk, K) output refs may be bf16 — the
-    # cast happens only at the final ref store below; the resident q/es
+    # cast happens only at the per-round row stores below; the resident q/es
     # carries and all round math stay full precision, so the *trajectory*
     # (and the final state) is bit-identical to the unstreamed run.
     """One grid step = ``chunk`` sequential OCEAN rounds on the resident state.
 
     Ref layout (after the closure statics):
-      inputs:  h2 (chunk, K), v (chunk,), eta (chunk,), inc (chunk, K)
-               [+ the 7 TracedRadio leaves, (chunk,) each, iff has_radio]
+      inputs:  h2 (chunk, K), v (chunk, 1), eta (chunk, 1), inc (chunk, K)
+               [+ the 7 TracedRadio leaves, (chunk, 1) each, iff has_radio]
                [+ dlv (chunk, K) streamed delivery mask and rate (1, K)
                declared stationary rates — the same slot every step, like
                the restored carry — iff has_failure]
-               [+ q0 (1, K), es0 (1, K), t0 (1,) — the restored carry for
+               [+ q0 (1, K), es0 (1, K), t0 (1, 1) — the restored carry for
                a mid-trajectory segment launch — and one (1, ...) leaf
                per restored MetricsState leaf, iff has_init]
-      outputs: a, b, e, q_pre, rho (chunk, K); obj, nsel (chunk,);
-               [+ dlv (chunk, K) and ral (chunk,) iff has_failure;]
-               [+ fault_count, demoted, fallback (chunk,) int32 guard
+      outputs: a (chunk, K) int32, b, e, q_pre, rho (chunk, K);
+               obj, nsel (chunk, 1);
+               [+ dlv (chunk, K) int32 and ral (chunk, 1) iff has_failure;]
+               [+ fault_count, demoted, fallback (chunk, 1) int32 guard
                telemetry iff cfg.guard is set;]
                q_final, es_final (1, K) — rewritten every step, so after
                the last step they hold the end-of-trajectory state;
@@ -193,53 +252,69 @@ def _traj_kernel(
             for ref, leaf in zip(m_scrs, m_init_leaves):
                 ref[0] = leaf
 
-    fdtype = q_scr.dtype
-
     def step(i, carry):
-        (
-            q, es, a_c, b_c, e_c, qp_c, rho_c, obj_c, ns_c, fail_bufs,
-            guard_bufs, m_leaves, t_bufs,
-        ) = carry
+        q, es, m_leaves = carry
         # tl indexes rounds within THIS launch (drives validity masking of
         # chunk-padded tails); t is the global Alg. 1 round (drives frame
         # resets).  They coincide unless this is a resumed segment.
         t = tl = ic * chunk + i
         if has_init:
-            t = t0_ref[0] + tl
+            t = t0_ref[0, 0] + tl
+        v_t = v_ref[i, 0]
+        eta_t = eta_ref[i, 0]
         radio_t = (
-            TracedRadio(*(r[i] for r in radio_refs)) if has_radio else None
+            TracedRadio(*(r[i, 0] for r in radio_refs)) if has_radio else None
         )
-        state = OceanState(q=q, t=t, energy_spent=es)
-        new_state, dec = ocean_round(
-            state,
-            h2_ref[i],
-            v_ref[i],
-            eta_ref[i],
-            cfg,
-            budget_inc=inc_ref[i],
-            radio=radio_t,
-            delivered=dlv_ref[i] if has_failure else None,
-            fail_rate=rate_ref[0] if has_failure else None,
+        row = pl.ds(i, 1)
+
+        def round_row(q, es, h2, inc, dlv, rate):
+            return ocean_round(
+                OceanState(q=q, t=t, energy_spent=es),
+                h2,
+                v_t,
+                eta_t,
+                cfg,
+                budget_inc=inc,
+                radio=radio_t,
+                delivered=dlv,
+                fail_rate=rate,
+            )
+
+        # The round runs vmapped over a size-1 row axis: every (K,) client
+        # vector becomes a (1, K) tile row.  Mosaic mislays rank-1 vectors,
+        # and the batched round computes the same values as ``scan``'s.
+        new_state, dec = jax.vmap(round_row)(
+            q,
+            es,
+            h2_ref[row, :],
+            inc_ref[row, :],
+            dlv_ref[row, :] if has_failure else None,
+            rate_ref[...] if has_failure else None,
         )
+        # Every round's decision goes straight to its row of the output
+        # tile: a dynamic sublane store, where a carried (chunk, K) buffer
+        # would need a dynamic update Mosaic cannot lower.
+        a_ref[row, :] = dec.a.astype(a_ref.dtype)
+        b_ref[row, :] = dec.b.astype(b_ref.dtype)
+        e_ref[row, :] = dec.e.astype(e_ref.dtype)
+        qp_ref[row, :] = dec.q.astype(qp_ref.dtype)
+        rho_ref[row, :] = dec.rho.astype(rho_ref.dtype)
+        _put(obj_ref, i, dec.objective)
+        _put(ns_ref, i, dec.num_selected)
         if has_failure:
-            dlv_c, ral_c = fail_bufs
-            fail_bufs = (
-                dlv_c.at[i].set(dec.delivered),
-                ral_c.at[i].set(dec.realloc),
-            )
+            dlvo_ref[row, :] = dec.delivered.astype(dlvo_ref.dtype)
+            _put(ral_ref, i, dec.realloc)
         if has_guard:
-            fc_c, dm_c, fb_c = guard_bufs
-            guard_bufs = (
-                fc_c.at[i].set(dec.fault_count),
-                dm_c.at[i].set(dec.demoted),
-                fb_c.at[i].set(dec.fallback),
-            )
+            _put(fco_ref, i, dec.fault_count)
+            _put(dmo_ref, i, dec.demoted)
+            _put(fbo_ref, i, dec.fallback)
         # Chunk-padded tail rounds (tl >= T) stream edge-replicated inputs:
         # their math runs but must not advance the resident carry.
         valid = tl < num_rounds
         if spec is not None:
+            unrow = functools.partial(jax.tree_util.tree_map, lambda x: x[0])
             ctx = round_context(
-                t, dec, new_state, v_ref[i], eta_ref[i], inc_ref[i],
+                t, unrow(dec), unrow(new_state), v_t, eta_t, inc_ref[i],
                 radio_t if has_radio else cfg.radio,
             )
             mstate, traces = metrics_round(
@@ -247,74 +322,19 @@ def _traj_kernel(
                 valid=valid,
             )
             m_leaves = tuple(jax.tree_util.tree_leaves(mstate))
-            t_bufs = tuple(
-                buf.at[i].set(traces[metric_key(name, "full_trace")])
-                for buf, name in zip(t_bufs, spec.full_trace_entries)
-            )
+            for ref, name in zip(trace_refs, spec.full_trace_entries):
+                ref[i] = traces[metric_key(name, "full_trace")].astype(ref.dtype)
         q = jnp.where(valid, new_state.q, q)
         es = jnp.where(valid, new_state.energy_spent, es)
-        return (
-            q,
-            es,
-            a_c.at[i].set(dec.a),
-            b_c.at[i].set(dec.b),
-            e_c.at[i].set(dec.e),
-            qp_c.at[i].set(dec.q),
-            rho_c.at[i].set(dec.rho),
-            obj_c.at[i].set(dec.objective),
-            ns_c.at[i].set(dec.num_selected),
-            fail_bufs,
-            guard_bufs,
-            m_leaves,
-            t_bufs,
-        )
+        return q, es, m_leaves
 
-    zf = jnp.zeros((chunk, K), fdtype)
-    carry0 = (
-        q_scr[0],
-        es_scr[0],
-        jnp.zeros((chunk, K), jnp.bool_),
-        zf, zf, zf, zf,
-        jnp.zeros((chunk,), fdtype),
-        jnp.zeros((chunk,), jnp.int32),
-        (
-            (jnp.zeros((chunk, K), jnp.bool_), jnp.zeros((chunk,), jnp.int32))
-            if has_failure
-            else ()
-        ),
-        (
-            tuple(jnp.zeros((chunk,), jnp.int32) for _ in range(3))
-            if has_guard
-            else ()
-        ),
-        tuple(ref[0] for ref in m_scrs),
-        tuple(jnp.zeros(ref.shape, ref.dtype) for ref in trace_refs),
-    )
-    (
-        q, es, a_c, b_c, e_c, qp_c, rho_c, obj_c, ns_c, fail_bufs,
-        guard_bufs, m_leaves, t_bufs,
-    ) = jax.lax.fori_loop(0, chunk, step, carry0)
+    carry0 = (q_scr[...], es_scr[...], tuple(ref[0] for ref in m_scrs))
+    q, es, m_leaves = jax.lax.fori_loop(0, chunk, step, carry0)
     with trace_span("traj/chunk_io"):
-        q_scr[0] = q
-        es_scr[0] = es
-        a_ref[...] = a_c
-        b_ref[...] = b_c.astype(b_ref.dtype)
-        e_ref[...] = e_c.astype(e_ref.dtype)
-        qp_ref[...] = qp_c.astype(qp_ref.dtype)
-        rho_ref[...] = rho_c.astype(rho_ref.dtype)
-        obj_ref[...] = obj_c
-        ns_ref[...] = ns_c
-        if has_failure:
-            dlvo_ref[...] = fail_bufs[0]
-            ral_ref[...] = fail_bufs[1]
-        if has_guard:
-            fco_ref[...] = guard_bufs[0]
-            dmo_ref[...] = guard_bufs[1]
-            fbo_ref[...] = guard_bufs[2]
-        qf_ref[0] = q
-        esf_ref[0] = es
-        for ref, buf in zip(trace_refs, t_bufs):
-            ref[...] = buf
+        q_scr[...] = q
+        es_scr[...] = es
+        qf_ref[...] = q
+        esf_ref[...] = es
         for scr, ref, leaf in zip(m_scrs, mfinal_refs, m_leaves):
             scr[0] = leaf
             ref[0] = leaf
@@ -355,8 +375,8 @@ def ocean_trajectory_fused(
     Same contract as the ``lax.scan`` body of ``repro.core.ocean.simulate``
     (which normalizes ``v``/``budgets`` before dispatching here): returns
     the final :class:`OceanState` and the stacked per-round
-    :class:`RoundDecision`.  ``interpret=None`` auto-selects interpret
-    mode off-TPU (the validated CPU fallback).  Batching: ``jax.vmap``
+    :class:`RoundDecision`.  ``interpret=None`` interprets on the CPU
+    backend and compiles everywhere else.  Batching: ``jax.vmap``
     over this function prepends cell grid dimensions to the kernel — the
     grid engine's (scenario, seed) axes become batched cells of one
     launch.
@@ -396,37 +416,47 @@ def ocean_trajectory_fused(
         )
     fdtype = jnp.result_type(h2_seq.dtype, jnp.float32)
     if chunk is None:
-        chunk = min(DEFAULT_CHUNK, max(1, CHUNK_ELEM_BUDGET // max(K, 1)))
+        chunk = min(
+            DEFAULT_CHUNK,
+            max(ROW_TILE, CHUNK_ELEM_BUDGET // max(K, 1) // ROW_TILE * ROW_TILE),
+        )
     chunk = max(1, min(chunk, T))
+    if not interpret:
+        check_fused_lowerable(cfg, failure_seq is not None, stream_bf16)
+        if chunk % ROW_TILE and chunk != T:
+            raise ValueError(
+                f"chunk={chunk} rounds per tile cannot lower on a TPU: it "
+                f"must be a multiple of {ROW_TILE} or cover all T={T} rounds"
+            )
     pad = (-T) % chunk
     n_chunks = (T + pad) // chunk
+    Tp = n_chunks * chunk
 
     has_radio = radio_seq is not None
     has_failure = failure_seq is not None
     has_guard = cfg.guard is not None
+
+    def rows(x, dtype):
+        # (T, K) streams tile as (chunk, K); per-round scalars as (Tp, 1)
+        # columns — a rank-1 (chunk,) block is not a legal TPU tile.
+        x = _pad_rounds(jnp.asarray(x, dtype), pad)
+        return x if x.ndim == 2 else x.reshape(Tp, 1)
+
     inputs = [
-        _pad_rounds(jnp.asarray(h2_seq, fdtype), pad),
-        _pad_rounds(jnp.asarray(v_seq, jnp.float32), pad),
-        _pad_rounds(jnp.asarray(eta_seq, jnp.float32), pad),
-        _pad_rounds(jnp.asarray(budget_seq, jnp.float32), pad),
+        rows(h2_seq, fdtype),
+        rows(v_seq, jnp.float32),
+        rows(eta_seq, jnp.float32),
+        rows(budget_seq, jnp.float32),
     ]
     if has_radio:
-        inputs.extend(
-            _pad_rounds(jnp.asarray(leaf, jnp.float32), pad)
-            for leaf in radio_seq
-        )
+        inputs.extend(rows(leaf, jnp.float32) for leaf in radio_seq)
     if has_failure:
         # Streamed like the other per-round (T, K) inputs; the fixed (K,)
         # declared rates ride as a whole-array block appended below.
-        inputs.append(
-            _pad_rounds(jnp.asarray(failure_seq.delivered, jnp.float32), pad)
-        )
-    n_streamed = len(inputs)
+        inputs.append(rows(failure_seq.delivered, jnp.float32))
 
-    def row_spec(x):
-        if x.ndim == 2:
-            return pl.BlockSpec((chunk, K), lambda ic: (ic, 0))
-        return pl.BlockSpec((chunk,), lambda ic: (ic,))
+    def row_spec(width):
+        return pl.BlockSpec((chunk, width), lambda ic: (ic, 0))
 
     def _chunked_spec(shape):
         block = (chunk,) + shape
@@ -436,7 +466,6 @@ def ocean_trajectory_fused(
         block = (1,) + shape
         return pl.BlockSpec(block, lambda ic, _n=len(shape): (0,) * (1 + _n))
 
-    Tp = n_chunks * chunk
     sdtype = jnp.bfloat16 if stream_bf16 else fdtype
     kernel = functools.partial(
         _traj_kernel,
@@ -447,7 +476,7 @@ def ocean_trajectory_fused(
         has_failure=has_failure,
         has_init=has_init,
     )
-    in_specs = [row_spec(x) for x in inputs[:n_streamed]]
+    in_specs = [row_spec(x.shape[1]) for x in inputs]
     if has_failure:
         inputs.append(jnp.asarray(failure_seq.rate, jnp.float32).reshape(1, K))
         in_specs.append(pl.BlockSpec((1, K), lambda ic: (0, 0)))
@@ -458,10 +487,10 @@ def ocean_trajectory_fused(
         inputs.append(
             jnp.asarray(init_state.energy_spent, fdtype).reshape(1, K)
         )
-        inputs.append(jnp.asarray(init_state.t, jnp.int32).reshape(1))
+        inputs.append(jnp.asarray(init_state.t, jnp.int32).reshape(1, 1))
         in_specs.append(pl.BlockSpec((1, K), lambda ic: (0, 0)))
         in_specs.append(pl.BlockSpec((1, K), lambda ic: (0, 0)))
-        in_specs.append(pl.BlockSpec((1,), lambda ic: (0,)))
+        in_specs.append(pl.BlockSpec((1, 1), lambda ic: (0, 0)))
         if cfg.metrics is not None:
             for leaf in jax.tree_util.tree_leaves(init_mstate):
                 leaf = jnp.asarray(leaf)
@@ -472,35 +501,28 @@ def ocean_trajectory_fused(
                         block, lambda ic, _n=leaf.ndim: (0,) * (1 + _n)
                     )
                 )
-    out_specs = [
-        pl.BlockSpec((chunk, K), lambda ic: (ic, 0)),   # a
-        pl.BlockSpec((chunk, K), lambda ic: (ic, 0)),   # b
-        pl.BlockSpec((chunk, K), lambda ic: (ic, 0)),   # e
-        pl.BlockSpec((chunk, K), lambda ic: (ic, 0)),   # q_pre
-        pl.BlockSpec((chunk, K), lambda ic: (ic, 0)),   # rho
-        pl.BlockSpec((chunk,), lambda ic: (ic,)),       # objective
-        pl.BlockSpec((chunk,), lambda ic: (ic,)),       # num_selected
-    ]
+    # Masks leave the kernel as int32 (bool and int8 rows do not lower) and
+    # per-round scalars as (Tp, 1) columns; both are undone below.
+    out_specs = [row_spec(K)] * 5 + [row_spec(1)] * 2
     out_shape = [
-        jax.ShapeDtypeStruct((Tp, K), jnp.bool_),
-        jax.ShapeDtypeStruct((Tp, K), sdtype),
-        jax.ShapeDtypeStruct((Tp, K), sdtype),
-        jax.ShapeDtypeStruct((Tp, K), sdtype),
-        jax.ShapeDtypeStruct((Tp, K), sdtype),
-        jax.ShapeDtypeStruct((Tp,), fdtype),
-        jax.ShapeDtypeStruct((Tp,), jnp.int32),
+        jax.ShapeDtypeStruct((Tp, K), jnp.int32),    # a
+        jax.ShapeDtypeStruct((Tp, K), sdtype),       # b
+        jax.ShapeDtypeStruct((Tp, K), sdtype),       # e
+        jax.ShapeDtypeStruct((Tp, K), sdtype),       # q_pre
+        jax.ShapeDtypeStruct((Tp, K), sdtype),       # rho
+        jax.ShapeDtypeStruct((Tp, 1), fdtype),       # objective
+        jax.ShapeDtypeStruct((Tp, 1), jnp.int32),    # num_selected
     ]
     if has_failure:
-        out_specs.append(pl.BlockSpec((chunk, K), lambda ic: (ic, 0)))  # dlv
-        out_specs.append(pl.BlockSpec((chunk,), lambda ic: (ic,)))      # ral
-        out_shape.append(jax.ShapeDtypeStruct((Tp, K), jnp.bool_))
-        out_shape.append(jax.ShapeDtypeStruct((Tp,), jnp.int32))
+        out_specs += [row_spec(K), row_spec(1)]
+        out_shape.append(jax.ShapeDtypeStruct((Tp, K), jnp.int32))  # dlv
+        out_shape.append(jax.ShapeDtypeStruct((Tp, 1), jnp.int32))  # ral
     if has_guard:
         # fault_count / demoted / fallback guard telemetry, streamed like
         # the failure extension's realloc counter.
         for _ in range(3):
-            out_specs.append(pl.BlockSpec((chunk,), lambda ic: (ic,)))
-            out_shape.append(jax.ShapeDtypeStruct((Tp,), jnp.int32))
+            out_specs.append(row_spec(1))
+            out_shape.append(jax.ShapeDtypeStruct((Tp, 1), jnp.int32))
     out_specs.append(pl.BlockSpec((1, K), lambda ic: (0, 0)))           # q_final
     out_specs.append(pl.BlockSpec((1, K), lambda ic: (0, 0)))           # es_final
     out_shape.append(jax.ShapeDtypeStruct((1, K), fdtype))
@@ -536,18 +558,25 @@ def ocean_trajectory_fused(
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
+        # Chunks carry the queues from one grid step to the next.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         interpret=interpret,
     )(*inputs)
     n_fixed = 9 + (2 if has_failure else 0) + (3 if has_guard else 0)
     a, b, e, q_pre, rho, obj, nsel = out[:7]
+    a = a.astype(jnp.bool_)
+    obj, nsel = obj[:, 0], nsel[:, 0]
     off = 7
     if has_failure:
         dlv, ral = out[off : off + 2]
+        dlv, ral = dlv.astype(jnp.bool_), ral[:, 0]
         off += 2
     else:
         dlv = ral = None
     if has_guard:
-        fc, dm, fb = out[off : off + 3]
+        fc, dm, fb = (x[:, 0] for x in out[off : off + 3])
         off += 3
     else:
         fc = dm = fb = None

@@ -8,15 +8,18 @@ import, and smoke tests/benches must keep seeing 1 device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh() -> jax.sharding.Mesh:
     """Trivial 1x1 mesh over the local device — used by smoke tests."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh(
+        (1, 1), ("data", "model"), axis_types=(AxisType.Auto, AxisType.Auto)
+    )

@@ -188,14 +188,29 @@ def _c_queue_next(cfg, ctx, state):
     return _f32(ctx.q_next), state
 
 
+def _sum_sq(x):
+    """sum(x * x) by pairwise halving, in one fixed association.
+
+    A ``jnp.sum`` reduces through different trees in the scan step (run
+    batched under the grid engine's vmaps) and in the fused kernel (run
+    per cell), which left the two Lyapunov traces 1 ulp apart.
+    """
+    x = x * x
+    n = x.shape[-1]
+    x = jnp.pad(x, (0, (1 << max(n - 1, 0).bit_length()) - n))
+    while x.shape[-1] > 1:
+        half = x.shape[-1] // 2
+        x = x[:half] + x[half:]
+    return x[0]
+
+
 def _c_lyapunov(cfg, ctx, state):
-    q = _f32(ctx.q)
-    return 0.5 * jnp.sum(q * q), state
+    return 0.5 * _sum_sq(_f32(ctx.q)), state
 
 
 def _c_lyapunov_drift(cfg, ctx, state):
     q, qn = _f32(ctx.q), _f32(ctx.q_next)
-    return 0.5 * (jnp.sum(qn * qn) - jnp.sum(q * q)), state
+    return 0.5 * (_sum_sq(qn) - _sum_sq(q)), state
 
 
 def _c_dpp_penalty(cfg, ctx, state):

@@ -84,16 +84,11 @@ def wall_span(name: str, recorder: Optional[SpanRecorder] = None):
     """Host-side span: TraceAnnotation (if a trace is active) + wall timer.
 
     ``TraceAnnotation`` is a cheap no-op outside an active
-    ``jax.profiler`` trace, so benchmarks wrap phases unconditionally;
-    guarded for jax builds without the API.
+    ``jax.profiler`` trace, so benchmarks wrap phases unconditionally.
     """
     recorder = SPANS if recorder is None else recorder
-    try:
-        annotation = jax.profiler.TraceAnnotation(name)
-    except Exception:  # pragma: no cover - profiler API unavailable
-        annotation = contextlib.nullcontext()
     t0 = time.perf_counter()
-    with annotation:
+    with jax.profiler.TraceAnnotation(name):
         try:
             yield
         finally:

@@ -70,7 +70,6 @@ from typing import Dict, NamedTuple, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.checkpoint import trajectory as ckpt_io
@@ -342,12 +341,12 @@ class GridEngine:
         if self._shard:
             mesh = Mesh(np.asarray(devices), ("cells",))
             pc, rep = PartitionSpec("cells"), PartitionSpec()
-            fn = shard_map(
+            fn = jax.shard_map(
                 self._build_flat,
                 mesh=mesh,
                 in_specs=(pc, pc, pc, pc, pc, pc, pc, pc, rep, pc),
                 out_specs=pc,
-                check_rep=False,
+                check_vma=False,
             )
             # Flattened inputs are rebuilt per run() call, so their buffers
             # can be donated to the program (XLA aliases them into the

@@ -229,10 +229,8 @@ def test_x64_newton_matches_bisect_near_tie_boundaries():
     argmax selection set even on draws engineered to sit near W*(S_m) ==
     W*(S_{m+1}) tie boundaries (clustered priorities that differ at the
     ~1e-9 relative level, invisible in float32)."""
-    from jax.experimental import enable_x64
-
     rng = np.random.default_rng(42)
-    with enable_x64():
+    with jax.enable_x64(True):
         for trial in range(10):
             k = int(rng.integers(4, 12))
             # clustered rho: pairs of nearly identical priorities
