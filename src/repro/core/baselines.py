@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.core.energy import RadioParams, energy, min_bandwidth_for_energy
 from repro.core.ocean import OceanConfig
 from repro.core.selection import ocean_p
+from repro.obs.spans import trace_span
 
 Array = jax.Array
 
@@ -99,14 +100,16 @@ def select_all(
 # --------------------------------------------------------------------------
 def _myopic_round(h2: Array, budget: Array, radio: RadioParams):
     """Greedy of §VI-A: cheapest-bandwidth clients first until B is exhausted."""
-    b_dag = min_bandwidth_for_energy(budget, h2, radio)   # (K,), inf if infeasible
-    order = jnp.argsort(b_dag)
-    b_sorted = b_dag[order]
-    csum = jnp.cumsum(jnp.where(jnp.isfinite(b_sorted), b_sorted, 1e9))
-    take_sorted = (csum <= 1.0) & jnp.isfinite(b_sorted)
-    inv = jnp.argsort(order)
-    a = take_sorted[inv]
-    b = jnp.where(a, b_dag, 0.0)
+    with trace_span("myopic/min_bandwidth"):
+        b_dag = min_bandwidth_for_energy(budget, h2, radio)  # (K,), inf if infeasible
+    with trace_span("myopic/greedy"):
+        order = jnp.argsort(b_dag)
+        b_sorted = b_dag[order]
+        csum = jnp.cumsum(jnp.where(jnp.isfinite(b_sorted), b_sorted, 1e9))
+        take_sorted = (csum <= 1.0) & jnp.isfinite(b_sorted)
+        inv = jnp.argsort(order)
+        a = take_sorted[inv]
+        b = jnp.where(a, b_dag, 0.0)
     return a, b
 
 

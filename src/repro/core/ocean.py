@@ -33,6 +33,7 @@ from repro.core.selection import (
 from repro.core.solvers import get_solver
 from repro.checkpoint.trajectory import CheckpointSpec
 from repro.guard.spec import GuardSpec
+from repro.obs.spans import trace_span
 from repro.obs.metrics import (
     MetricsSpec,
     finalize_metrics,
@@ -450,8 +451,9 @@ def ocean_round(
     R = cfg.R
     radio = cfg.radio if radio is None else radio
     # Frame boundary reset (Alg. 1 line 3-5): at t = m*R, m >= 1.
-    at_boundary = (state.t > 0) & (jnp.mod(state.t, R) == 0)
-    q = jnp.where(at_boundary, jnp.zeros_like(state.q), state.q)
+    with trace_span("ocean/queue"):
+        at_boundary = (state.t > 0) & (jnp.mod(state.t, R) == 0)
+        q = jnp.where(at_boundary, jnp.zeros_like(state.q), state.q)
 
     admit = fault_count = demoted = fb_flag = None
     if cfg.guard is not None:
@@ -477,7 +479,8 @@ def ocean_round(
             sol, fb_flag = _guard_fallback(cfg, q, h2, v, eta, radio, admit, sol)
         else:
             fb_flag = jnp.zeros((), jnp.int32)
-    e = energy(sol.b, h2, radio, sol.a)
+    with trace_span("ocean/energy"):
+        e = energy(sol.b, h2, radio, sol.a)
 
     a, b, objective, num_selected = sol.a, sol.b, sol.objective, sol.num_selected
     dlv = ral = None
@@ -487,23 +490,25 @@ def ocean_round(
             admit=admit,
         )
 
-    if budget_inc is None:
-        if budgets is None:
-            budgets = cfg.budgets()
-        budget_inc = budgets / cfg.num_rounds
-    if cfg.guard is not None and cfg.guard.quarantine:
-        # A corrupt budget draw must never reach the queue carry: a
-        # non-finite increment is treated as "no allowance this round".
-        budget_inc = jnp.where(
-            jnp.isfinite(budget_inc), budget_inc, jnp.zeros_like(budget_inc)
-        )
-    q_next = jnp.maximum(q + e - budget_inc, 0.0)
+    with trace_span("ocean/queue"):
+        if budget_inc is None:
+            if budgets is None:
+                budgets = cfg.budgets()
+            budget_inc = budgets / cfg.num_rounds
+        if cfg.guard is not None and cfg.guard.quarantine:
+            # A corrupt budget draw must never reach the queue carry: a
+            # non-finite increment is treated as "no allowance this round".
+            budget_inc = jnp.where(
+                jnp.isfinite(budget_inc), budget_inc,
+                jnp.zeros_like(budget_inc),
+            )
+        q_next = jnp.maximum(q + e - budget_inc, 0.0)
 
-    new_state = OceanState(
-        q=q_next,
-        t=state.t + 1,
-        energy_spent=state.energy_spent + e,
-    )
+        new_state = OceanState(
+            q=q_next,
+            t=state.t + 1,
+            energy_spent=state.energy_spent + e,
+        )
     dec = RoundDecision(
         a=a,
         b=b,

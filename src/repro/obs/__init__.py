@@ -6,7 +6,8 @@
   solver diagnostics) recorded *inside* the compiled scan / fused-kernel
   trajectories.
 * :mod:`repro.obs.spans` — ``jax.named_scope`` / profiler
-  ``TraceAnnotation`` wrappers plus host wall-clock span timers.
+  ``TraceAnnotation`` wrappers, the compiled program's scope table, and
+  host wall-clock span timers.
 * :mod:`repro.obs.manifest` — structured JSONL run manifests emitted by
   ``benchmarks/run.py``.
 """
@@ -34,7 +35,15 @@ from repro.obs.metrics import (
     round_context,
     solver_effort,
 )
-from repro.obs.spans import SPANS, SpanRecorder, record_span, trace_span, wall_span
+from repro.obs.spans import (
+    SPANS,
+    SpanRecorder,
+    host_span,
+    record_span,
+    scope_table,
+    trace_span,
+    wall_span,
+)
 
 __all__ = [
     "Collector",
@@ -52,6 +61,7 @@ __all__ = [
     "config_hash",
     "finalize_metrics",
     "get_collector",
+    "host_span",
     "init_metrics",
     "metric_key",
     "metrics_round",
@@ -59,6 +69,7 @@ __all__ = [
     "record_span",
     "round_context",
     "runs_in_manifest",
+    "scope_table",
     "solver_effort",
     "trace_span",
     "wall_span",

@@ -90,7 +90,7 @@ from repro.env.failure import TracedFailure, traced_failure
 from repro.env.radio import TracedRadio, sample_radio_process
 from repro.env.spec import env_cell_keys, failure_cell_key, radio_cell_key
 from repro.obs.metrics import MetricsSpec, finalize_metrics
-from repro.obs.spans import trace_span
+from repro.obs.spans import host_span, trace_span
 
 Array = jax.Array
 
@@ -830,29 +830,8 @@ class GridEngine:
             metrics, dlv, failure_seq,
         )
 
-    # -- public API ----------------------------------------------------------
-    def run(
-        self,
-        seeds: Sequence[int],
-        *,
-        base_key: Optional[Array] = None,
-        learn_keys: Optional[Array] = None,
-        learn_seed: int = 0,
-        resume_from: Union[str, bool, None] = None,
-    ) -> GridResult:
-        """Sweep the grid over ``seeds``; compiled once per grid shape.
-
-        ``learn_keys`` — optional explicit (S, N, 2) PRNG keys for the
-        learning trajectories (default: fold (scenario, seed) into
-        ``PRNGKey(learn_seed)``).  ``base_key`` seeds stochastic policies.
-
-        ``resume_from`` — restore the latest committed snapshot before
-        running: ``True`` resumes from the configured ``CheckpointSpec``
-        directory, a string names an explicit snapshot directory.  The
-        resumed sweep must use the same grid, seeds, and keys as the
-        interrupted one (snapshots hold only policy carries and trace
-        prefixes; environment streams are re-derived from the seeds).
-        """
+    def _keys(self, seeds, base_key, learn_keys, learn_seed):
+        """(seeds, seed array, base key, learn keys) of one sweep."""
         seeds = tuple(int(s) for s in seeds)
         seed_arr = jnp.asarray(seeds, jnp.uint32)
         S, N = len(self.scenarios), len(seeds)
@@ -878,53 +857,111 @@ class GridEngine:
                     f"learn_keys must have leading shape (S={S}, N={N}), "
                     f"got {learn_keys.shape}"
                 )
-        if self.cfg.checkpoint is not None or (
-            resume_from is not None and resume_from is not False
-        ):
-            (
-                a, b, e, ns, h2, budget_inc, budget_total, radio_seq, history,
-                metrics, dlv, failure_seq,
-            ) = self._run_segmented(seed_arr, base_key, learn_keys, resume_from)
-        elif self._shard:
-            (
-                a, b, e, ns, h2, budget_inc, budget_total, radio_seq, history,
-                metrics, dlv, failure_seq,
-            ) = self._run_sharded(seed_arr, base_key, learn_keys)
-        else:
-            (
-                a, b, e, ns, h2, budget_inc, budget_total, radio_seq, history,
-                metrics, dlv, failure_seq,
-            ) = self._fn(
-                seed_arr,
-                self._chan_params,
-                self._budget_params,
-                self._radio_params,
-                self._env_salts,
-                self._etas,
-                base_key,
-                learn_keys,
-                self._failure_params,
-            )
-        if all(m is None for m in metrics):
-            metrics = None  # metrics-off grid: keep the legacy None field
-        return GridResult(
-            a=a,
-            b=b,
-            e=e,
-            num_selected=ns,
-            energy_spent=e.sum(axis=-2),
-            h2=h2,
-            history=history,
-            policies=self.policies,
-            scenarios=tuple(sc.name for sc in self.scenarios),
-            seeds=seeds,
-            budget_inc=budget_inc,
-            budget_total=budget_total,
-            radio_seq=radio_seq,
-            metrics=metrics,
-            delivered=dlv,
-            failure_seq=failure_seq,
+        return seeds, seed_arr, base_key, learn_keys
+
+    def _program_args(self, seed_arr, base_key, learn_keys):
+        """The single-program path's arguments, in ``_build``'s order."""
+        return (
+            seed_arr,
+            self._chan_params,
+            self._budget_params,
+            self._radio_params,
+            self._env_salts,
+            self._etas,
+            base_key,
+            learn_keys,
+            self._failure_params,
         )
+
+    # -- public API ----------------------------------------------------------
+    def lower(self, seeds: Sequence[int]) -> jax.stages.Lowered:
+        """The program ``run(seeds)`` dispatches, lowered, not run.
+
+        Only the default single-program path has one program: a sharded
+        or checkpointed engine raises ``ValueError``.
+        """
+        if self._shard or self.cfg.checkpoint is not None:
+            raise ValueError(
+                "lower() covers the unsharded, unsegmented program only"
+            )
+        _, seed_arr, base_key, learn_keys = self._keys(seeds, None, None, 0)
+        return self._fn.lower(
+            *self._program_args(seed_arr, base_key, learn_keys)
+        )
+
+    def run(
+        self,
+        seeds: Sequence[int],
+        *,
+        base_key: Optional[Array] = None,
+        learn_keys: Optional[Array] = None,
+        learn_seed: int = 0,
+        resume_from: Union[str, bool, None] = None,
+    ) -> GridResult:
+        """Sweep the grid over ``seeds``; compiled once per grid shape.
+
+        ``learn_keys`` — optional explicit (S, N, 2) PRNG keys for the
+        learning trajectories (default: fold (scenario, seed) into
+        ``PRNGKey(learn_seed)``).  ``base_key`` seeds stochastic policies.
+
+        ``resume_from`` — restore the latest committed snapshot before
+        running: ``True`` resumes from the configured ``CheckpointSpec``
+        directory, a string names an explicit snapshot directory.  The
+        resumed sweep must use the same grid, seeds, and keys as the
+        interrupted one (snapshots hold only policy carries and trace
+        prefixes; environment streams are re-derived from the seeds).
+
+        Under an active profiler trace the call shows as three host spans:
+        ``grid/keys`` (seeds and PRNG keys), ``grid/dispatch`` (the
+        compiled program's call) and ``grid/result`` (the ``GridResult``).
+        """
+        with host_span("grid/keys"):
+            seeds, seed_arr, base_key, learn_keys = self._keys(
+                seeds, base_key, learn_keys, learn_seed
+            )
+        with host_span("grid/dispatch"):
+            if self.cfg.checkpoint is not None or (
+                resume_from is not None and resume_from is not False
+            ):
+                (
+                    a, b, e, ns, h2, budget_inc, budget_total, radio_seq,
+                    history, metrics, dlv, failure_seq,
+                ) = self._run_segmented(
+                    seed_arr, base_key, learn_keys, resume_from
+                )
+            elif self._shard:
+                (
+                    a, b, e, ns, h2, budget_inc, budget_total, radio_seq,
+                    history, metrics, dlv, failure_seq,
+                ) = self._run_sharded(seed_arr, base_key, learn_keys)
+            else:
+                (
+                    a, b, e, ns, h2, budget_inc, budget_total, radio_seq,
+                    history, metrics, dlv, failure_seq,
+                ) = self._fn(
+                    *self._program_args(seed_arr, base_key, learn_keys)
+                )
+        with host_span("grid/result"):
+            if all(m is None for m in metrics):
+                metrics = None  # metrics-off grid: keep the legacy None field
+            return GridResult(
+                a=a,
+                b=b,
+                e=e,
+                num_selected=ns,
+                energy_spent=e.sum(axis=-2),
+                h2=h2,
+                history=history,
+                policies=self.policies,
+                scenarios=tuple(sc.name for sc in self.scenarios),
+                seeds=seeds,
+                budget_inc=budget_inc,
+                budget_total=budget_total,
+                radio_seq=radio_seq,
+                metrics=metrics,
+                delivered=dlv,
+                failure_seq=failure_seq,
+            )
 
 
 def run_grid(
