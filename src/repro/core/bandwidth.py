@@ -18,6 +18,25 @@ whole solver jits, vmaps (over candidate selection sets — OCEAN-P) and
 differentiates-nowhere (it is piecewise constant in integers; we never need
 gradients through it).
 
+Layout of the inner loop.  The inner bisection is 42 dependent steps inside
+each of the 42 outer ones, so its layout sets the solver's cost.  A TPU
+vector register is 8 sublanes by 128 lanes, and the minor axis of an array
+goes on the lanes.  With K < 128 clients the (K+1, K) candidate lattice,
+and each vmapped copy of it (the grid's scenarios and seeds), would sit in
+mostly padded tiles: at (S, N) = (3, 10), K = 10, a hundred (4, 128) tiles
+hold 3300 values.  So below 128 clients the inner loop runs on a
+lane-dense ``(rows, 128)`` slab whose ``vmap`` rule folds every batch
+level into its elements (``_slab_bisection``): at that size, 26 rows in
+four registers.  The slab is padded to whole rows only (fewer than 128 extra
+elements); the TPU rounds the rows up to (8, 128) tiles itself, and a
+backend without tiling, such as the CPU, carries no more than that.  At
+K >= 128 the loop runs in the lattice's own shape: a v5e compile of the
+(3, 10)-vmapped lattice puts the clients on the lanes there
+(``{3,2,1,0:T(8,128)}``), so 129 of 136 sublanes carry values at K = 128
+and 200 of 256 lanes at K = 200 (``tests/test_tpu_compile.py``), and a
+slab would add relayouts for little.  The outer bisection, its masked sum
+over the clients and the budget repair keep the lattice shape.
+
 This replaces the CVX calls of the paper with an accelerator-native exact
 solver (see DESIGN.md §3, hardware adaptation).
 """
@@ -29,23 +48,19 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.energy import RadioParams, f_shannon, f_shannon_prime
+from repro.obs.spans import trace_span
 
 Array = jax.Array
 
 
-def _b_of_lam(
-    lam: Array, rho: Array, beta: float, b_min: float, b_max: Array, iters: int
-) -> Array:
-    """Solve rho_k f'(b) = -lam for each k by bisection; clamp to [b_min, b_max].
+LANES = 128  # lanes of a TPU vector register: the slab's minor width
+# What the slab's padding lanes hold: target, lo, hi, beta.  f'(1) at
+# beta = 1 is finite, so they stay finite (``jax_debug_nans``).
+_SLAB_FILL = (-1.0, 1.0, 1.0, 1.0)
 
-    f' is strictly increasing, so we bisect on b.  Where rho_k == 0 the
-    client has no energy cost and the KKT stationarity never binds; callers
-    mask those out (they sit in S0 with b = b_min).
-    """
-    target = -lam / jnp.maximum(rho, 1e-30)  # want f'(b) = target (<0)
 
-    lo = jnp.full_like(rho, b_min)
-    hi = jnp.broadcast_to(b_max, rho.shape).astype(rho.dtype)
+def _bisect_steps(target: Array, lo: Array, hi: Array, beta, iters: int) -> Array:
+    """``iters`` halvings of ``[lo, hi]`` toward f'(b) = target, elementwise."""
 
     def body(_, carry):
         lo, hi = carry
@@ -57,6 +72,74 @@ def _b_of_lam(
 
     lo, hi = jax.lax.fori_loop(0, iters, body, (lo, hi))
     return 0.5 * (lo + hi)
+
+
+def _slab_bisection(iters: int):
+    """``_bisect_steps`` over every element at once, on a ``(rows, 128)`` slab.
+
+    The four operands share one shape; ``beta`` is per element.  Under
+    ``vmap`` the batching rule broadcasts the unbatched operands and folds
+    the new axis into the element axis, so each vmap level (OCEAN's
+    candidates, the grid's scenarios and seeds) adds elements to the one
+    slab instead of a padded tile dimension.  The body flattens, pads to
+    whole 128-lane rows with ``_SLAB_FILL``, runs the loop and cuts the
+    padding off again: the relayout happens once per call, never inside
+    the loop.
+    """
+
+    @jax.custom_batching.custom_vmap
+    def bisect(target, lo, hi, beta):
+        shape, n = target.shape, target.size
+        pad = -n % LANES
+
+        def slab(x, fill):
+            return jnp.pad(x.reshape(-1), (0, pad), constant_values=fill).reshape(
+                -1, LANES
+            )
+
+        with trace_span("p4/bisect/inner_slab"):
+            b = _bisect_steps(*map(slab, (target, lo, hi, beta), _SLAB_FILL), iters)
+        return b.reshape(-1)[:n].reshape(shape)
+
+    @bisect.def_vmap
+    def _fold(axis_size, in_batched, *args):
+        args = [
+            a if batched else jnp.broadcast_to(a, (axis_size,) + a.shape)
+            for a, batched in zip(args, in_batched)
+        ]
+        return bisect(*args), True
+
+    return bisect
+
+
+def _b_of_lam(
+    lam: Array, rho: Array, beta: float, b_min: float, b_max: Array, iters: int
+) -> Array:
+    """Solve rho_k f'(b) = -lam for each k by bisection; clamp to [b_min, b_max].
+
+    f' is strictly increasing, so we bisect on b.  Where rho_k == 0 the
+    client has no energy cost and the KKT stationarity never binds; callers
+    mask those out (they sit in S0 with b = b_min).
+
+    Layout: with fewer clients than a vector register has lanes
+    (K < 128), the TPU's tiling pads the lattice — and, under ``vmap``,
+    every batched copy of it — to mostly empty tiles, so the 42-step loop
+    would push mostly padding through each step.  There it runs on a
+    lane-dense slab (``_slab_bisection``).  At K >= 128 the clients fill
+    the lanes (the module docstring gives the compile reading) and the
+    loop runs in the lattice's own shape.  Both run the same operations on
+    each element in the same order, so ``b`` has the same bits either way.
+    """
+    target = -lam / jnp.maximum(rho, 1e-30)  # want f'(b) = target (<0)
+
+    lo = jnp.full_like(rho, b_min)
+    hi = jnp.broadcast_to(b_max, rho.shape).astype(rho.dtype)
+    if rho.shape[-1] < LANES:
+        beta = jnp.broadcast_to(
+            jnp.asarray(beta, jnp.result_type(beta, lo)), rho.shape
+        )
+        return _slab_bisection(iters)(target, lo, hi, beta)
+    return _bisect_steps(target, lo, hi, beta, iters)
 
 
 def solve_p4(

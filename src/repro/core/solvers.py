@@ -13,6 +13,10 @@ module makes the solver a pluggable backend:
     The original double bisection, verbatim (moved here from
     ``selection.ocean_p`` / dispatched to ``bandwidth.solve_p4``).  It is
     the default so every existing figure benchmark stays byte-stable.
+    Below 128 clients its inner bisection runs on a lane-dense
+    ``(rows, 128)`` slab into which every vmap level (candidates, seeds,
+    scenarios) folds, and at K >= 128 in the lattice's own layout; both
+    give the same bits (``repro.core.bandwidth``).
 
 ``newton``
     Safeguarded Newton waterfilling.  Two nested root-finds replace the

@@ -261,3 +261,102 @@ def test_x64_newton_matches_bisect_near_tie_boundaries():
             assert float(sol.objective) == pytest.approx(
                 float(ref.objective), rel=1e-6, abs=1e-12
             )
+
+
+# -- bisect's inner bisection on a lane-dense slab -------------------------
+def _rho_sorted(rng, shape):
+    q, h2 = _draw(rng, int(np.prod(shape)))
+    return jnp.sort(priorities(q, h2).reshape(shape), axis=-1)
+
+
+def _prefixes(rho, radio):
+    sol = get_solver("bisect").prefixes(
+        rho, jnp.int32(0), jnp.asarray(1.0, rho.dtype),
+        jnp.asarray(1e-5, rho.dtype), radio, 42, 42,
+    )
+    return sol.m_star, sol.w_star, sol.b_pos_sorted, sol.sel_pos_sorted
+
+
+def _slab_case_unbatched():
+    rho = _rho_sorted(np.random.default_rng(20), (10,))
+    mask = jnp.arange(10) < 7
+    return lambda r: solve_p4(r, mask, jnp.asarray(0.9), RADIO), (rho,)
+
+
+def _slab_case_one_vmap():
+    return lambda r: _prefixes(r, RADIO), (_rho_sorted(np.random.default_rng(21), (10,)),)
+
+
+def _slab_case_nested():
+    """The engine's (S, N) vmap around the candidate vmap, with a traced
+    radio whose bandwidth and b_min differ per cell."""
+    from repro.env.radio import TracedRadio, traced_radio
+
+    s, n, k = 3, 4, 10
+    base = traced_radio(RADIO)
+    bw = jnp.asarray(np.linspace(5e6, 2e7, s * n).reshape(s, n), jnp.float32)
+    b_min = jnp.asarray(np.linspace(0.01, 0.05, s * n).reshape(s, n), jnp.float32)
+    leaves = {f: jnp.broadcast_to(jnp.asarray(getattr(base, f)), (s, n))
+              for f in TracedRadio._fields}
+    leaves.update(bandwidth_hz=bw, b_min=b_min,
+                  beta=base.model_bits / (base.deadline_s * bw),
+                  energy_scale=base.deadline_s * base.noise_w * bw)
+    cell = lambda r, leaves: _prefixes(r, TracedRadio(**leaves))
+    rho = _rho_sorted(np.random.default_rng(22), (s, n, k))
+    return jax.vmap(jax.vmap(cell)), (rho, leaves)
+
+
+def _slab_case_float64():
+    rho = _rho_sorted(np.random.default_rng(23), (10,)).astype(jnp.float64)
+    return lambda r: _prefixes(r, RADIO), (rho,)
+
+
+def _slab_case_demoted():
+    from repro.core.selection import RHO_DEMOTED
+
+    rho = _rho_sorted(np.random.default_rng(24), (10,)).at[-2:].set(RHO_DEMOTED)
+    return lambda r: _prefixes(r, RADIO), (rho,)
+
+
+def _slab_case_k128():
+    radio = RadioParams(b_min=0.005)
+    return lambda r: _prefixes(r, radio), (_rho_sorted(np.random.default_rng(25), (128,)),)
+
+
+@pytest.mark.parametrize("case", ["unbatched", "one_vmap", "nested", "float64",
+                                  "demoted", "k128"])
+def test_bisect_slab_equals_lattice_loop_bitwise(case, monkeypatch):
+    """Below 128 clients the inner bisection runs on a (rows, 128) slab and
+    gives the same bits as the loop in the lattice's own shape; at K = 128
+    the slab does not engage."""
+    from repro.core import bandwidth
+
+    with jax.enable_x64(case == "float64"):
+        fn, args = globals()[f"_slab_case_{case}"]()
+        jaxpr = str(jax.make_jaxpr(fn)(*args))
+        slab = jax.jit(fn)(*args)
+        monkeypatch.setattr(bandwidth, "LANES", 0)  # every K takes the loop
+        loop = jax.jit(lambda *a: fn(*a))(*args)  # a new function: traced anew
+    k = args[0].shape[-1]
+    assert ("custom_vmap_call" in jaxpr) == (k < 128)
+    if case == "float64":
+        assert slab[1].dtype == jnp.float64
+    for got, want in zip(jax.tree_util.tree_leaves(slab), jax.tree_util.tree_leaves(loop)):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_bisect_slab_pad_lanes_stay_finite():
+    """Under ``jax.debug_nans`` every step of the loop over lanes that hold
+    only the slab's padding stays finite (op by op), and so does a slab
+    solve."""
+    from repro.core import bandwidth
+
+    pad = [jnp.full((8, 128), fill, jnp.float32) for fill in bandwidth._SLAB_FILL]
+    rho = _rho_sorted(np.random.default_rng(26), (11, 10))
+    lam = jnp.linspace(0.0, 1e-3, 11)[:, None]
+    with jax.debug_nans(True):
+        with jax.disable_jit():
+            b_pad = bandwidth._bisect_steps(*pad, 42)
+        b = bandwidth._b_of_lam(lam, rho, RADIO.beta, RADIO.b_min, jnp.full((11, 1), 0.5), 42)
+    assert np.all(np.isfinite(np.asarray(b_pad))) and np.all(np.isfinite(np.asarray(b)))

@@ -11,6 +11,7 @@ process may load the TPU library, and pytest-xdist workers import every
 test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.energy import RadioParams
 from repro.core.ocean import OceanConfig, OceanState, simulate
+from repro.core.solvers import get_solver
 from repro.env.failure import TracedFailure
 from repro.env.radio import TracedRadio
 from repro.kernels import ocean_traj
@@ -144,3 +146,55 @@ def test_default_scan_bisect_program_compiles(one_chip):
     h2 = jax.ShapeDtypeStruct((t, k), jnp.float32, sharding=one_chip)
     text = _compile_text(lambda h: simulate(cfg, h, eta, 1e-5), h2)
     assert "tpu_custom_call" not in text
+
+
+def _bisect_sweep_whiles(one_chip, s, n, k):
+    """The ``while`` lines of the sweep's bisect P4 compiled at the engine's
+    (S, N) vmap around the candidate lattice, K clients."""
+
+    def cell(rho, delta, v_eta, leaves):
+        sol = get_solver("bisect").prefixes(
+            rho, jnp.int32(0), delta, v_eta, TracedRadio(*leaves), 42, 42
+        )
+        return sol.m_star, sol.w_star, sol.b_pos_sorted, sol.sel_pos_sorted
+
+    f32 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    leaves = tuple(f32((s, n)) for _ in TracedRadio._fields)
+    text = _compile_text(
+        jax.vmap(jax.vmap(cell)), f32((s, n, k)), f32((s, n)), f32((s, n)), leaves
+    )
+    return [line for line in text.splitlines() if " while(" in line]
+
+
+def test_bisect_inner_loop_runs_on_a_lane_dense_slab(one_chip):
+    """The sweep's bisect P4 at (S, N) = (3, 10), K = 10: each inner
+    bisection ``while`` carries (rows, 128) f32 slabs in (8, 128) tiles,
+    padded by less than one row, and no loop carries the padded
+    (S, N, K+1, K) lattice."""
+    s, n, k = 3, 10, 10
+    whiles = _bisect_sweep_whiles(one_chip, s, n, k)
+    inner = [w.split(" while(")[0] for w in whiles if "p4/bisect/inner_slab" in w]
+    assert len(inner) == 2  # one per outer trip, one for the final b(lam)
+    for carry in inner:
+        slabs = re.findall(r"f32\[(\d+),128\]\{1,0:T\(8,128\)", carry)
+        assert len(slabs) == len(re.findall(r"f32\[", carry)) == 4
+        assert all(0 <= int(rows) * 128 - s * n * (k + 1) * k < 128 for rows in slabs)
+    assert not any(f"f32[{s},{n},{k + 1},{k}]" in w.split(" while(")[0] for w in whiles)
+
+
+@pytest.mark.parametrize("k", (128, 200))
+def test_bisect_lattice_puts_clients_on_the_lanes_from_k128(one_chip, k):
+    """Where the slab does not engage (K >= 128), the inner bisection
+    ``while`` carries the (S, N, K+1, K) lattice with the clients on the
+    lanes and the candidates on the sublanes, in (8, 128) tiles: the
+    lattice is lane-dense in its own layout."""
+    s, n = 3, 10
+    whiles = _bisect_sweep_whiles(one_chip, s, n, k)
+    assert not any("p4/bisect/inner_slab" in w for w in whiles)
+    lattice = f"f32[{s},{n},{k + 1},{k}]"
+    inner = [w.split(" while(")[0] for w in whiles if lattice in w.split(" while(")[0]]
+    assert len(inner) == 2
+    for carry in inner:
+        layouts = re.findall(re.escape(lattice) + r"(\{[^}]*\})", carry)
+        assert len(layouts) == 2, layouts  # lo and hi
+        assert all(lay.startswith("{3,2,1,0:T(8,128)") for lay in layouts), layouts
