@@ -1,5 +1,7 @@
 """The numbers that decide ``correct``: what the timed path produced, held to
-the plain reference (``reference.py``) and to the configuration's guarantees.
+the deployment's plain reference (``ref``, the module its configuration names
+under ``reference``; ``reference.py`` for the paper's) and to the
+configuration's guarantees.
 
 Each number is the worst reading over the answers checked in a run:
 
@@ -18,7 +20,7 @@ Each number is the worst reading over the answers checked in a run:
 * ``sum_b_excess``, ``b_min_shortfall``: how far the band is overspent, and
   how far a selected client falls below b_min, relative to it.
 * ``h2_gap``: largest relative gap between the channel gains the program
-  sampled and the paper's fading drawn from the same seed.
+  sampled and the deployment's channel law drawn from the same seed.
 * ``myopic_b_gap``: largest relative gap between the bandwidth SMO/AMO gave a
   selected client and its cheapest bandwidth within its cap;
   ``myopic_sel_wrong``: selections that differ from the reference's greedy
@@ -30,8 +32,6 @@ import contextlib
 from typing import Dict, Optional
 
 import numpy as np
-
-import reference as ref
 
 SEL_MARGIN = 1e-3
 
@@ -78,7 +78,7 @@ def bandwidth(nums: Numbers, a, b, b_min: float) -> None:
         nums.add("sum_b_excess", np.inf)
 
 
-def energy(nums: Numbers, a, b, e, h2, radio: ref.Radio) -> None:
+def energy(nums: Numbers, ref, a, b, e, h2, radio) -> None:
     a = np.asarray(a, bool)
     e = np.asarray(e, np.float64)
     e_ref = ref.energy(np.where(a, b, 0.0), h2, radio)
@@ -88,7 +88,7 @@ def energy(nums: Numbers, a, b, e, h2, radio: ref.Radio) -> None:
     nums.add("energy_gap", gap.max(initial=0.0))
 
 
-def p3(nums: Numbers, a, b, q, h2, v_eta: float, radio: ref.Radio) -> None:
+def p3(nums: Numbers, ref, a, b, q, h2, v_eta: float, radio) -> None:
     q64, h64 = ref.exact(q), ref.exact(h2)
     with np.errstate(divide="ignore"):
         rho = q64 / np.maximum(h64, ref.RHO_ZERO)
@@ -97,7 +97,7 @@ def p3(nums: Numbers, a, b, q, h2, v_eta: float, radio: ref.Radio) -> None:
     nums.add("p3_gap", abs(best.w - got) / ref.p3_scale(best, q64, h64, v_eta, radio))
 
 
-def queue(nums: Numbers, q_used, e, q_next, inc,
+def queue(nums: Numbers, ref, q_used, e, q_next, inc,
           q_carried: Optional[np.ndarray] = None, reset: bool = False) -> None:
     """The update rule, and (given the carried queue) the frame reset."""
     q_used = np.asarray(q_used, np.float32)
@@ -109,31 +109,31 @@ def queue(nums: Numbers, q_used, e, q_next, inc,
     nums.add("queue_gap", gap)
 
 
-def ocean_round(nums: Numbers, *, a, b, e, q_used, h2, v_eta: float,
-                radio: ref.Radio, q_next=None, inc=None, q_carried=None,
+def ocean_round(nums: Numbers, ref, *, a, b, e, q_used, h2, v_eta: float,
+                radio, q_next=None, inc=None, q_carried=None,
                 reset=False) -> None:
     """Every number of one OCEAN round's answer; the queue's where the
     program returned the next one (``q_next``)."""
     if q_next is not None:
-        queue(nums, q_used, e, q_next, inc, q_carried, reset)
-    p3(nums, a, b, q_used, h2, v_eta, radio)
-    energy(nums, a, b, e, h2, radio)
+        queue(nums, ref, q_used, e, q_next, inc, q_carried, reset)
+    p3(nums, ref, a, b, q_used, h2, v_eta, radio)
+    energy(nums, ref, a, b, e, h2, radio)
     bandwidth(nums, a, b, radio.b_min)
 
 
-def control_round(nums: Numbers, *, q_used, h2, v_eta: float,
-                  radio: ref.Radio, inc=None, q_carried=None, reset=False) -> None:
+def control_round(nums: Numbers, ref, *, q_used, h2, v_eta: float,
+                  radio, inc=None, q_carried=None, reset=False) -> None:
     """The same numbers for the reference put in the program's place,
     computed in bfloat16, on the same inputs; the queue's where ``inc`` is
     given."""
     c = ref.ocean_round(q_used, h2, v_eta, 0.0 if inc is None else inc, radio,
                         rnd=ref.bf16)
-    ocean_round(nums, a=c.a, b=c.b, e=c.e, q_used=ref.bf16(q_used),
+    ocean_round(nums, ref, a=c.a, b=c.b, e=c.e, q_used=ref.bf16(q_used),
                 q_next=None if inc is None else c.q_next, h2=h2, v_eta=v_eta,
                 inc=inc, radio=radio, q_carried=q_carried, reset=reset)
 
 
-def myopic_round(nums: Numbers, *, a, b, e, cap, h2, radio: ref.Radio) -> None:
+def myopic_round(nums: Numbers, ref, *, a, b, e, cap, h2, radio) -> None:
     """One SMO/AMO round's answer against the reference greedy."""
     want = ref.myopic_round(cap, h2, radio)
     a = np.asarray(a, bool)
@@ -144,17 +144,17 @@ def myopic_round(nums: Numbers, *, a, b, e, cap, h2, radio: ref.Radio) -> None:
              .max(initial=0.0))
     wrong = (a != want.a) & (want.margin > SEL_MARGIN)
     nums.add("myopic_sel_wrong", float(wrong.sum()))
-    energy(nums, a, b, e, h2, radio)
+    energy(nums, ref, a, b, e, h2, radio)
     bandwidth(nums, a, b, radio.b_min)
 
 
-def control_myopic(nums: Numbers, *, cap, h2, radio: ref.Radio) -> None:
+def control_myopic(nums: Numbers, ref, *, cap, h2, radio) -> None:
     c = ref.myopic_round(cap, h2, radio, rnd=ref.bf16)
     b = np.where(c.a, c.b_dag, 0.0)
     e = ref.energy(b, h2, radio, ref.bf16)
-    myopic_round(nums, a=c.a, b=b, e=e, cap=cap, h2=h2, radio=radio)
+    myopic_round(nums, ref, a=c.a, b=b, e=e, cap=cap, h2=h2, radio=radio)
 
 
 def channel(nums: Numbers, h2, h2_ref) -> None:
-    rel = np.abs(ref.exact(h2) - h2_ref) / h2_ref
+    rel = np.abs(np.asarray(h2, np.float64) - h2_ref) / h2_ref
     nums.add("h2_gap", rel.max(initial=0.0))

@@ -1,4 +1,4 @@
-"""A configuration file as the program's objects, and the cells' channel data.
+"""A configuration file as the program's objects, and its reference module.
 
 A configuration (``bench/configs/<name>.json``) states a deployment: the
 population, horizon and radio, the scenarios and policies of a sweep, the
@@ -6,29 +6,31 @@ execution path it runs today (``exec``), the guarantees the comparison holds
 it to and the limit of each number compared.  This module turns it into the
 program's ``OceanConfig``, ``Scenario``s and policy specs.
 
-The closed-loop cell feeds the program channel reports that the benchmark
-draws itself, on the device, from the seed: the paper's block fading,
-h2[t, k] = 10^(-PL_t / 10) x Exp(1).
+A scenario gives its channel as ``pathloss_db`` (the paper's law), and may
+carry ``env``, the JSON of a ``repro.env.EnvSpec`` (``EnvSpec.to_dict()``),
+which the program then samples instead.  The configuration's ``reference``
+names, from the checkout's root, the module of the deployment's plain
+reference: its semantics and its channel law on both sides of the check.
 """
 from __future__ import annotations
 
+from types import ModuleType
 from typing import List
 
-import jax
-import jax.numpy as jnp
-import numpy as np
+import harness
 
-import reference as ref
+
+def reference(conf: dict) -> ModuleType:
+    """The deployment's reference module, loaded from the path its
+    configuration names."""
+    path = harness.ROOT / conf["reference"]
+    return harness.load_module(path, "reference_" + path.stem)
 
 
 def radio(conf: dict):
     from repro.core.energy import RadioParams
 
     return RadioParams(**conf["radio"])
-
-
-def reference_radio(conf: dict) -> ref.Radio:
-    return ref.Radio(**conf["radio"])
 
 
 def ocean_config(conf: dict):
@@ -46,20 +48,24 @@ def ocean_config(conf: dict):
 
 def scenarios(conf: dict) -> List:
     from repro.core import Scenario
+    from repro.env import EnvSpec
 
-    return [
-        Scenario(
+    out = []
+    for s in conf["scenarios"]:
+        channel = {"pathloss_db": tuple(s["pathloss_db"])} if "pathloss_db" in s else {}
+        if "env" in s:
+            channel["env"] = EnvSpec.from_dict(s["env"])
+        out.append(Scenario(
             name=s["name"],
             num_clients=conf["num_clients"],
             num_rounds=conf["num_rounds"],
             frame_len=conf["frame_len"],
-            pathloss_db=tuple(s["pathloss_db"]),
             radio=radio(conf),
             energy_budget_j=conf["energy_budget_j"],
+            **channel,
             **conf["exec"],
-        )
-        for s in conf["scenarios"]
-    ]
+        ))
+    return out
 
 
 def policies(conf: dict) -> List:
@@ -67,19 +73,3 @@ def policies(conf: dict) -> List:
 
     return [(p["name"], PolicyParams(v=p["v"])) if "v" in p else p["name"]
             for p in conf["policies"]]
-
-
-def key_of(seed: int, stream: int) -> jax.Array:
-    """A PRNG key for one of the benchmark's streams, from any whole seed."""
-    words = np.random.SeedSequence([int(seed), stream]).generate_state(2)
-    return jax.random.wrap_key_data(jnp.asarray(words, jnp.uint32),
-                                    impl="threefry2x32")
-
-
-def channel_bank(conf: dict, seed: int) -> jax.Array:
-    """One (T, K) draw of the first scenario's channel gains, on device."""
-    t, k = conf["num_rounds"], conf["num_clients"]
-    gain = jnp.asarray(ref.pathloss_gain(conf["scenarios"][0]["pathloss_db"], t),
-                       jnp.float32)
-    key = key_of(seed, 100)
-    return gain[:, None] * jax.random.exponential(key, (t, k), jnp.float32)

@@ -9,9 +9,10 @@ its parameters; the configuration gives the deployment.  Each driver
   and returns its end-to-end metrics, from the host clock;
 * ``release()``: drops what the window made except the answers kept for
   the check;
-* ``check(nums, control)``: holds the kept answers to the reference and,
-  with ``control``, puts the reference computed in bfloat16 in the
-  program's place on the same inputs.
+* ``check(nums, control)``: holds the kept answers to the deployment's
+  reference (the module its configuration names) and, with ``control``,
+  puts the reference computed in bfloat16 in the program's place on the
+  same inputs.
 
 Answers are kept by a reservoir sample drawn from the seed, so which rounds
 are checked does not depend on how many the window completes.
@@ -28,7 +29,6 @@ import numpy as np
 
 import checks
 import deploy
-import reference as ref
 
 
 def _span(traced: bool, name: str):
@@ -61,10 +61,12 @@ class Reservoir:
 
 class Driver:
     span_names: tuple = ()      # the host spans idle gaps are put down to
+    unit_span: str = ""         # the host span that starts each unit of work
 
     def __init__(self, conf: dict, traffic: dict, seed: int) -> None:
         self.conf, self.traffic, self.seed = conf, traffic, seed
-        self.radio = deploy.reference_radio(conf)
+        self.ref = deploy.reference(conf)
+        self.radio = self.ref.Radio(**conf["radio"])
         self.units = 0               # rounds or cell-rounds of the window
         self.info: Dict = {}          # printed on an earlier output line
         self.host: Dict[str, List[float]] = {}
@@ -80,6 +82,7 @@ class ClosedLoop(Driver):
     with the configuration's first OCEAN policy."""
 
     span_names = ("upload", "solve", "fetch")
+    unit_span = "upload"
 
     def setup(self) -> None:
         from repro.core.ocean import init_state, ocean_round
@@ -88,8 +91,8 @@ class ClosedLoop(Driver):
         cfg = deploy.ocean_config(conf)
         self.t_rounds, self.frame = conf["num_rounds"], conf["frame_len"]
         pol = next(p for p in conf["policies"] if p["kind"] == "ocean")
-        self.bank = np.asarray(deploy.channel_bank(conf, self.seed))
-        self.eta = ref.eta(pol["eta"], self.t_rounds).astype(np.float32)
+        self.bank = np.asarray(self.ref.bank(conf, self.seed))
+        self.eta = self.ref.eta(pol["eta"], self.t_rounds).astype(np.float32)
         self.v = pol["v"]
         v = np.float32(self.v)
         self.fn = jax.jit(lambda st, h2, eta_t: ocean_round(st, h2, v, eta_t, cfg))
@@ -141,7 +144,7 @@ class ClosedLoop(Driver):
             first = self.i - len(counts)
             self.info["selected_per_round"] = dict(
                 _selected(counts),
-                frame_start_rounds=sum(ref.frame_reset(t, self.frame)
+                frame_start_rounds=sum(self.ref.frame_reset(t, self.frame)
                                        for t in range(first, self.i)))
         ms = np.asarray(lat) * 1e3
         return {"decision_ms_p50": float(np.median(ms)),
@@ -155,7 +158,7 @@ class ClosedLoop(Driver):
         self.state = self.fn = None
 
     def check(self, nums: checks.Numbers, control: bool = False) -> None:
-        inc = self._inc()
+        ref, inc = self.ref, self._inc()
         for i, (q_in, q_used, e, q_next), a, b in self.kept.items:
             row = i % self.t_rounds
             args = dict(q_used=q_used, h2=self.bank[row],
@@ -164,9 +167,10 @@ class ClosedLoop(Driver):
                         reset=ref.frame_reset(i, self.frame))
             with nums.answer():
                 if control:
-                    checks.control_round(nums, **args)
+                    checks.control_round(nums, ref, **args)
                 else:
-                    checks.ocean_round(nums, a=a, b=b, e=e, q_next=q_next, **args)
+                    checks.ocean_round(nums, ref, a=a, b=b, e=e, q_next=q_next,
+                                       **args)
 
 
 # ------------------------------------------------------------ grid sweep
@@ -174,6 +178,7 @@ class GridSweep(Driver):
     """Back-to-back ``GridEngine.run`` sweeps, fresh channel seeds each."""
 
     span_names = ("sweep",)
+    unit_span = "sweep"
 
     def setup(self) -> None:
         from repro.sim import GridEngine
@@ -226,7 +231,7 @@ class GridSweep(Driver):
         self.engine = None
 
     def check(self, nums: checks.Numbers, control: bool = False) -> None:
-        c = self.conf
+        c, ref = self.conf, self.ref
         t_rounds, k = c["num_rounds"], c["num_clients"]
         budget = np.float32(c["energy_budget_j"])
         inc = self._inc()
@@ -234,10 +239,9 @@ class GridSweep(Driver):
         for seeds, a, b, e, h2, budget_inc in self.kept:
             for s, scn in enumerate(c["scenarios"]):
                 for n, seed in enumerate(seeds):
-                    pl = scn["pathloss_db"]
-                    h2_ref = ref.channel(seed, t_rounds, k, pl)
+                    h2_ref = ref.channel(seed, t_rounds, k, scn)
                     with nums.answer():
-                        checks.channel(nums, ref.channel(seed, t_rounds, k, pl, ref.bf16)
+                        checks.channel(nums, ref.channel(seed, t_rounds, k, scn, ref.bf16)
                                        if control else h2[s, n], h2_ref)
                         gap = np.abs(budget_inc[s, n] - inc).max()
                         nums.add("drain_gap", 0.0 if control else gap)
@@ -260,10 +264,10 @@ class GridSweep(Driver):
                         v_eta = pol["v"] * float(ref.eta(pol["eta"], t_rounds)[t])
                         args = dict(q_used=q, h2=h2_t, v_eta=v_eta, radio=self.radio)
                         if control:
-                            checks.control_round(nums, inc=None, **args)
+                            checks.control_round(nums, ref, inc=None, **args)
                         else:
-                            checks.ocean_round(nums, a=a[p, s, n, t], b=b[p, s, n, t],
-                                               e=e[p, s, n, t], **args)
+                            checks.ocean_round(nums, ref, a=a[p, s, n, t],
+                                               b=b[p, s, n, t], e=e[p, s, n, t], **args)
                     else:
                         if pol["kind"] == "smo":
                             cap = np.full(k, inc, np.float64)
@@ -271,11 +275,12 @@ class GridSweep(Driver):
                             cap = ref.amo_caps(np.full(k, budget), e[p, s, n], t,
                                                t_rounds)
                         if control:
-                            checks.control_myopic(nums, cap=cap, h2=h2_t, radio=self.radio)
+                            checks.control_myopic(nums, ref, cap=cap, h2=h2_t,
+                                                  radio=self.radio)
                         else:
-                            checks.myopic_round(nums, a=a[p, s, n, t], b=b[p, s, n, t],
-                                                e=e[p, s, n, t], cap=cap, h2=h2_t,
-                                                radio=self.radio)
+                            checks.myopic_round(nums, ref, a=a[p, s, n, t],
+                                                b=b[p, s, n, t], e=e[p, s, n, t],
+                                                cap=cap, h2=h2_t, radio=self.radio)
 
 
 DRIVERS = {"closed_loop": ClosedLoop, "grid_sweep": GridSweep}

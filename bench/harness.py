@@ -4,16 +4,21 @@
 
 The cell, its configuration, its traffic mix and its per-layer metrics are
 found by name: the cell in ``BENCHMARK.json`` at the checkout's root, the
-configuration in the file it names, the mix in ``bench/traffic/<mix>.json``
-and each per-layer metric's reader in ``bench/layer_metrics/<metric>.py``.
+configuration in the file it names, the deployment's reference in the module
+the configuration names (``bench/deploy.py``), the mix in
+``bench/traffic/<mix>.json`` and each per-layer metric's reader in
+``bench/layer_metrics/<metric>.py``.
 
 A run: set-up (imports, inputs drawn from the seed, compilation through the
 persistent cache in ``<checkout>/.jax_cache``, warm-up), then the window of
 ``--seconds`` driven by the mix's driver, then the check of the answers the
 window produced against the plain reference.  ``--trace 1`` traces a window
 of the mix's ``trace_seconds`` instead and reports the per-layer metrics read
-from that trace.  Without an accelerator, or with fewer chips than the cell
-asks for, the run exits 3 and prints no result.
+from that trace; where the profiler dropped device ops, they read the part
+of the window before the first unit of work that lost some, and a window
+that lost ops from its first unit is traced once again (``reduce_trace``).
+Without an accelerator, or with fewer chips than the cell asks for, the run
+exits 3 and prints no result.
 """
 from __future__ import annotations
 
@@ -32,6 +37,11 @@ BENCH = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH.parent
 NO_CHIP = 3
 COMPILE_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+# Traced windows a run makes at most, while each lost device ops from its
+# first unit of work on.  Tracing and reading a window takes about as long
+# as the probe after it (a 0.5 s sweep window some 100 s on a v5e), and a
+# second window takes a traced sweep run near its time limit.
+TRACE_TRIES = 2
 
 
 def process_age_s() -> Optional[float]:
@@ -71,23 +81,55 @@ def find_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     return Cell(name, cell["chips"], conf, traffic, e2e, layer)
 
 
-def reader(metric: str):
-    path = BENCH / "layer_metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        "layer_metric_" + metric.replace(".", "_"), path)
+def load_module(path: pathlib.Path, name: str):
+    """The Python file at ``path``, loaded as a module of its own."""
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(metric: str):
+    path = BENCH / "layer_metrics" / f"{metric}.py"
+    return load_module(path, "layer_metric_" + metric.replace(".", "_")).read
 
 
 class Reading(NamedTuple):
     """What a per-layer metric's reader may read."""
 
-    reduced: object          # tracing.Reduced of the traced window
-    units: int               # rounds (or cell-rounds) the window completed
+    reduced: object          # tracing.Reduced of the traced window's whole part
+    units: int               # rounds (or cell-rounds) completed in that part
     host: Dict[str, list]    # the benchmark's host spans, seconds each
     conf: dict
     device_kind: str
+
+
+class Window(NamedTuple):
+    """A traced window, reduced over its whole part: the window up to the
+    first ``unit`` span from which the profiler dropped device ops, or all
+    of it where none was dropped."""
+
+    reduced: object          # tracing.Reduced of that part
+    kept: int                # unit spans that start in that part
+    units: int               # unit spans in the window
+
+
+def reduce_trace(dev_ops, host, labels, unit: str) -> Window:
+    """The traced window, reduced over the part of it that holds every
+    device op (``tracing.cut_at``), so that busy time, idle time and the
+    breakdown stay true of what they cover."""
+    import tracing
+
+    window = tracing.find_span(host, "window")
+    starts = [s.start for s in host if s.name == unit
+              and window.start <= s.start and s.end <= window.end]
+    cut = tracing.cut_at(dev_ops, host, window, unit)
+    if cut is None:
+        return Window(tracing.reduce_window(dev_ops, host, window, labels),
+                      len(starts), len(starts))
+    part = tracing.Span(window.name, window.start, cut)
+    return Window(tracing.reduce_window(dev_ops, host, part, labels),
+                  sum(1 for t in starts if t < cut), len(starts))
 
 
 def _log(msg: str) -> None:
@@ -147,21 +189,28 @@ def _execute(cell, seed, seconds, trace, require_chip, t_start, devices, cache,
     drv.setup()
     setup_s = time.perf_counter() - t_start
     before = len(compiles)
-    reduced = None
     if trace:
         import tracing
 
-        tmp = tempfile.mkdtemp(prefix="bench_trace_")
-        try:
-            with jax.profiler.trace(tmp):
-                with jax.profiler.TraceAnnotation("window"):
-                    drv.run(cell.traffic["trace_seconds"], traced=True)
-            dev_ops, host = tracing.read_xplane(tracing.latest_xplane(tmp))
-        finally:
-            shutil.rmtree(tmp, ignore_errors=True)
-        reduced = tracing.reduce_window(dev_ops, host,
-                                        tracing.find_span(host, "window"),
-                                        drv.span_names)
+        for _ in range(TRACE_TRIES):
+            t0 = time.perf_counter()
+            tmp = tempfile.mkdtemp(prefix="bench_trace_")
+            try:
+                with jax.profiler.trace(tmp):
+                    with jax.profiler.TraceAnnotation("window"):
+                        drv.run(cell.traffic["trace_seconds"], traced=True)
+                t1 = time.perf_counter()
+                dev_ops, host = tracing.read_xplane(tracing.latest_xplane(tmp))
+            finally:
+                shutil.rmtree(tmp, ignore_errors=True)
+            win = reduce_trace(dev_ops, host, drv.span_names, drv.unit_span)
+            _log(f"bench: traced window {t1 - t0:.1f} s, read "
+                 f"{time.perf_counter() - t1:.1f} s, {win.kept} of {win.units} "
+                 f"{drv.unit_span} spans before the first that lost device ops")
+            if win.kept:
+                break
+        drv.info["trace_whole"] = win.kept == win.units
+        drv.info["trace_units_kept"] = [win.kept, win.units]
         measured: Dict[str, float] = {}
     else:
         measured = drv.run(seconds, traced=False)
@@ -180,7 +229,9 @@ def _execute(cell, seed, seconds, trace, require_chip, t_start, devices, cache,
     units = {m["name"]: m["unit"] for m in cell.end_to_end + cell.per_layer}
     metrics = {}
     if trace:
-        reading = Reading(reduced, drv.units, drv.host, cell.conf,
+        shown = win.reduced if win.kept else None
+        kept_units = drv.units * win.kept // win.units if win.units else 0
+        reading = Reading(shown, kept_units, drv.host, cell.conf,
                           devices[0].device_kind)
         for m in cell.per_layer:
             value = reader(m["name"])(reading)
@@ -195,10 +246,11 @@ def _execute(cell, seed, seconds, trace, require_chip, t_start, devices, cache,
     result = {"correct": bool(correct), "attempted": int(drv.units),
               "failed": int(nums.failed), "metrics": metrics, "device": device}
     if trace:
-        device["busy_s"] = reduced.busy_s
-        device["window_s"] = reduced.window_s
-        result["breakdown"] = {"device_ops": reduced.device_ops,
-                               "idle_gaps": reduced.idle_gaps}
+        device["busy_s"] = win.reduced.busy_s
+        device["window_s"] = win.reduced.window_s
+        if shown is not None:
+            result["breakdown"] = {"device_ops": shown.device_ops,
+                                   "idle_gaps": shown.idle_gaps}
     rows.append(["compiles_in_window", float(in_window), 0.0])
     # JSON has no infinity: a number that is off every scale reads as the
     # largest float, which breaks every limit as infinity would.

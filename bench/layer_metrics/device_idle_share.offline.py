@@ -1,5 +1,7 @@
 """Share of the traced window in which no op ran on the device, in %:
-1 - (union of the device's op intervals) / (window), averaged over chips."""
+1 - (union of the device's op intervals) / (window), averaged over chips,
+over the part of the window that kept every device op
+(``harness.reduce_trace``)."""
 
 
 def read(r):

@@ -1,6 +1,7 @@
 """Device time per round of every op that is not a Pallas kernel, in ms: on
 the default path the whole of ``ocean_round`` (ranking, the bisect P4 solve,
-energy, queue update)."""
+energy, queue update), over the rounds of the part of the traced window
+that kept every device op (``harness.reduce_trace``)."""
 
 
 def read(r):

@@ -17,6 +17,12 @@ independent of the code under test: nothing here imports ``repro``.
 * SMO/AMO (§VI-A): each client's cheapest bandwidth meeting its per-round
   energy cap, then the clients in ascending order of that bandwidth while
   the running sum stays within the band.
+* The channel law, on both sides of the check: ``bank`` draws the closed
+  loop's inputs, ``channel`` the gains a sweep's seed must give.
+
+This is the reference of the configurations that name it under
+``reference``.  Another deployment's module may import this one and replace
+what differs, as a rule its channel law (``channel`` and ``bank``).
 
 A ``Rounding`` passed through the functions below rounds the result of each
 operation; ``bf16`` gives the control that a result computed in a lower
@@ -316,15 +322,39 @@ def frame_reset(t: int, frame_len: int) -> bool:
     return t > 0 and t % frame_len == 0
 
 
-def channel(seed: int, num_rounds: int, num_clients: int, pathloss_db,
+def channel(seed: int, num_rounds: int, num_clients: int, scenario: dict,
             rnd: Rounding = exact):
-    """(T, K) gains of the paper's i.i.d. Rayleigh block fading for a seed:
-    the uniform draws of ``jax.random.PRNGKey(seed)`` in [1e-6, 1), made
-    Exp(1) and scaled by the path-loss schedule."""
+    """(T, K) gains of the paper's i.i.d. Rayleigh block fading for a seed
+    and a scenario of the configuration: the uniform draws of
+    ``jax.random.PRNGKey(seed)`` in [1e-6, 1), made Exp(1) and scaled by the
+    scenario's path-loss schedule (``pathloss_db``)."""
     import jax
 
     key = jax.random.PRNGKey(np.uint32(seed))
     u = rnd(jax.random.uniform(key, (num_rounds, num_clients), minval=1e-6,
                                maxval=1.0))
-    g = rnd(pathloss_gain(pathloss_db, num_rounds))
+    g = rnd(pathloss_gain(scenario["pathloss_db"], num_rounds))
     return rnd(g[:, None] * rnd(-np.log(u)))
+
+
+def key_of(seed: int, stream: int):
+    """A PRNG key for one of the benchmark's streams, from any whole seed."""
+    import jax
+    import jax.numpy as jnp
+
+    words = np.random.SeedSequence([int(seed), stream]).generate_state(2)
+    return jax.random.wrap_key_data(jnp.asarray(words, jnp.uint32),
+                                    impl="threefry2x32")
+
+
+def bank(conf: dict, seed: int):
+    """The closed loop's inputs: one (T, K) draw of the first scenario's
+    channel gains, h2[t, k] = 10^(-PL_t / 10) x Exp(1), on the device."""
+    import jax
+    import jax.numpy as jnp
+
+    t, k = conf["num_rounds"], conf["num_clients"]
+    gain = jnp.asarray(pathloss_gain(conf["scenarios"][0]["pathloss_db"], t),
+                       jnp.float32)
+    return gain[:, None] * jax.random.exponential(key_of(seed, 100), (t, k),
+                                                  jnp.float32)

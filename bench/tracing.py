@@ -16,9 +16,11 @@ that covers its midpoint.
 """
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
+import statistics
 from typing import Dict, Iterable, List, NamedTuple, Optional, Sequence, Tuple
 
 KERNEL_MARK = 'custom_call_target="tpu_custom_call"'
@@ -186,6 +188,47 @@ def _cover(marks: Sequence[Span], t: int) -> str:
         if s.end >= t and (best is None or s.start >= best.start):
             best = s
     return best.name if best else "other"
+
+
+def cut_at(devices: Sequence[Sequence[Op]], host: Sequence[Span], window: Span,
+           unit: str) -> Optional[int]:
+    """Where the trace stops holding every device op: None where it is
+    whole, else the start of the first ``unit`` span from which ops are
+    missing.
+
+    The profiler drops device op events: every one past its bound (6 273 800
+    on a v5e), and now and then some inside a long trace.  A unit (one
+    round, one sweep) runs the same programs every time: on a v5e at K = 10,
+    2631 op events a round and 907107 a sweep.  Each ``unit`` span in
+    ``window`` takes the ops that start from it to the next one, the first
+    also those before it and the last those after it, and the count most
+    units hold is a unit's.  Device times carry a clock offset of up to a
+    few ms, so a round's ops may land in the next round's interval, but
+    none is lost: the trace is whole where, on every chip, the units hold
+    as many ops as that count times their number.  Ops are missing from
+    the first unit after which the running count stays short.  With fewer
+    than two units nothing is compared."""
+    starts = sorted(s.start for s in host if s.name == unit
+                    and window.start <= s.start and s.end <= window.end)
+    if len(starts) < 2:
+        return None
+    cut: Optional[int] = None
+    for ops in devices:
+        counts = [0] * len(starts)
+        for o in ops:
+            counts[max(bisect.bisect_right(starts, o.start) - 1, 0)] += 1
+        held = [n for n in counts if n]
+        per_unit = statistics.mode(held) if held else 0
+        short, seen = None, 0
+        for i, n in enumerate(counts):
+            seen += n
+            if seen >= (i + 1) * per_unit:
+                short = None
+            elif short is None:
+                short = i
+        if short is not None and (cut is None or starts[short] < cut):
+            cut = starts[short]
+    return cut
 
 
 def find_span(host: List[Span], name: str) -> Span:
