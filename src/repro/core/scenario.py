@@ -40,7 +40,12 @@ from repro.core.selection import DEFAULT_BLOCK_K, DEFAULT_TOP_M, check_ranking
 from repro.core.solvers import get_solver
 from repro.guard.spec import GuardSpec
 from repro.obs.metrics import MetricsSpec
-from repro.env.channel import LowerCtx, get_channel_process, sample_channel_process
+from repro.env.channel import (
+    UMA_PROCESS,
+    LowerCtx,
+    get_channel_process,
+    sample_channel_process,
+)
 from repro.env.energy import sample_budget_process
 from repro.env.failure import TracedFailure, traced_failure
 from repro.env.radio import TracedRadio, sample_radio_process
@@ -259,7 +264,8 @@ class Scenario:
         lowered = self.lower_env()
         k_chan, _ = env_cell_keys(key, jnp.uint32(lowered.key_salt))
         return sample_channel_process(
-            lowered.channel, key, k_chan, self.num_rounds, self.num_clients
+            lowered.channel, key, k_chan, self.num_rounds, self.num_clients,
+            uma=self.env_spec().channel == UMA_PROCESS,
         )
 
     def sample_budget(self, seed_or_key: Union[int, Array]) -> Tuple[Array, Array]:

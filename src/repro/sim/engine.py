@@ -84,7 +84,7 @@ from repro.core.policy import (
     resolve_params,
 )
 from repro.core.scenario import Scenario
-from repro.env.channel import sample_channel_process
+from repro.env.channel import UMA_PROCESS, sample_channel_process
 from repro.env.energy import sample_budget_process
 from repro.env.failure import TracedFailure, traced_failure
 from repro.env.radio import TracedRadio, sample_radio_process
@@ -333,6 +333,11 @@ class GridEngine:
         self._env_salts = jnp.asarray(
             [l.key_salt for l in lowered], jnp.uint32
         )
+        # The UMa sector's float64 path is traced only where a scenario
+        # is a sector.
+        self._has_uma = any(
+            sc.env_spec().channel == UMA_PROCESS for sc in self.scenarios
+        )
         self._etas = jnp.stack([sc.eta_seq() for sc in self.scenarios])
 
         devices = jax.devices()
@@ -402,7 +407,8 @@ class GridEngine:
             fade_key = jax.random.PRNGKey(seed)
             k_chan, k_budget = env_cell_keys(fade_key, salt)
             k_radio = radio_cell_key(fade_key, salt)
-            h2 = sample_channel_process(cp, fade_key, k_chan, T, K)
+            h2 = sample_channel_process(cp, fade_key, k_chan, T, K,
+                                        uma=self._has_uma)
             dh, total = sample_budget_process(bp, k_budget, T, K)
             radio_seq = sample_radio_process(rp, k_radio, T)
             failure_seq = None
@@ -525,7 +531,8 @@ class GridEngine:
             fade_key = jax.random.PRNGKey(seed)
             k_chan, k_budget = env_cell_keys(fade_key, salt)
             k_radio = radio_cell_key(fade_key, salt)
-            h2 = sample_channel_process(cp, fade_key, k_chan, T, K)
+            h2 = sample_channel_process(cp, fade_key, k_chan, T, K,
+                                        uma=self._has_uma)
             dh, total = sample_budget_process(bp, k_budget, T, K)
             radio_seq = sample_radio_process(rp, k_radio, T)
             failure_seq = None

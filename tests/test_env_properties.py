@@ -17,6 +17,7 @@ _DEFAULT_PARAMS = {
     "gauss_markov": {"rho": 0.9},
     "markov_shadowing": {"p_enter": 0.2, "p_exit": 0.5, "extra_db": 8.0},
     "mobility": {"area_m": 60.0},
+    "uma_38901": {"speed_mps": 8.0, "round_s": 10.0},
 }
 
 
